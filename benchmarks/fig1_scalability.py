@@ -134,7 +134,10 @@ def run_store(out_json: str = STORE_JSON) -> dict:
 
 # One SPMD measurement process per graph: ``--xla_force_host_platform_
 # device_count`` must be set before jax imports, so the mesh runs in a
-# child interpreter that reports its records back as JSON on stdout.
+# child interpreter that reports its records back as JSON on stdout.  The
+# child is a CPU emulation of W hosts (the forced device count means nothing
+# on an accelerator), so it runs with JAX_PLATFORMS=cpu and never contends
+# with this process for a chip.
 _SPMD_SCRIPT = r'''
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -144,9 +147,8 @@ import tempfile
 import time
 
 import numpy as np
-import jax
-
 from repro.core import PMVEngine, cost_model, pagerank
+from repro.core.mesh import worker_mesh
 from repro.graph import rmat
 from repro.store import ingest_edges
 
@@ -170,7 +172,7 @@ with tempfile.TemporaryDirectory() as tmp:
         # stream (paper's graph-exceeds-memory regime, now per host).
         budget = max(total_bytes // (2 * W), 3 * slice_bytes)
         assert budget < total_bytes, (budget, total_bytes)
-        mesh = jax.make_mesh((W,), ("workers",))
+        mesh = worker_mesh(W)
         eng = PMVEngine(None, store=root, residency="disk",
                         strategy="vertical", mesh=mesh,
                         store_budget_bytes=budget)
@@ -217,12 +219,14 @@ def run_store_spmd() -> list:
     """SPMD out-of-core series: each graph solved on a W-worker mesh with
     per-worker budgets below the block set, bitwise-gated against the
     resident engine, reporting the measured prefetch overlap and the
-    per-worker wire/I-O split (plus the cost model's predicted overlap)."""
+    per-worker wire/I-O split (plus the cost model's predicted overlap).
+    Each graph runs in a JAX_PLATFORMS=cpu child: the W workers are emulated
+    CPU devices, and this process has already used JAX for run_store()."""
     series = []
     for log2n, m_edges in STORE_SIZES:
         params = {"log2n": log2n, "m_edges": m_edges, "iters": ITERS,
                   "b": B, "workers": SPMD_WORKERS}
-        env = {**os.environ,
+        env = {**os.environ, "JAX_PLATFORMS": "cpu",
                "PYTHONPATH": os.pathsep.join(
                    x for x in ("src", os.environ.get("PYTHONPATH", "")) if x)}
         proc = subprocess.run(

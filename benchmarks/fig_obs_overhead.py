@@ -61,13 +61,16 @@ SPMD_WORKERS = 4
 
 def _spmd_series(smoke: bool) -> dict:
     """W=4 SPMD disk series from the subprocess child (the mesh's emulated
-    device count must be set before jax imports, so not importable here)."""
+    device count must be set before jax imports, so not importable here).
+    The child is a CPU emulation, run with JAX_PLATFORMS=cpu so it never
+    contends with this process for an accelerator."""
     child = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                          "spmd_obs_child.py")
     cmd = [sys.executable, child, "--workers", str(SPMD_WORKERS)]
     if smoke:
         cmd.append("--smoke")
-    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=1800)
+    proc = subprocess.run(cmd, capture_output=True, text=True, timeout=1800,
+                          env={**os.environ, "JAX_PLATFORMS": "cpu"})
     if proc.returncode != 0:
         raise RuntimeError(f"spmd child failed:\n{proc.stderr}")
     return json.loads(proc.stdout)

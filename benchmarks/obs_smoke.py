@@ -16,6 +16,10 @@ artifacts the CI job uploads:
                                  PMVServer's /metrics endpoint
     OBS_smoke/parity.json        bitwise parity + span inventory report
 
+The SPMD leg runs benchmarks/spmd_obs_child.py as a child process with
+JAX_PLATFORMS=cpu: it emulates four hosts on forced CPU devices, and must
+never contend with this process for an accelerator.
+
 Exits non-zero on parity failure, schema violation, nesting violation,
 missing calibration kinds (ell / dense / disk_block / disk_io / spmd_io /
 spmd_overlap), a malformed merged SPMD trace, or a bad scrape.
@@ -112,7 +116,8 @@ def main(out_root: str = "OBS_smoke") -> int:
     try:
         proc = subprocess.run(
             [sys.executable, child, "--workers", "4", "--smoke"],
-            capture_output=True, text=True, timeout=1800)
+            capture_output=True, text=True, timeout=1800,
+            env={**os.environ, "JAX_PLATFORMS": "cpu"})
         if proc.returncode != 0:
             raise RuntimeError(proc.stderr[-2000:])
         spmd = json.loads(proc.stdout)

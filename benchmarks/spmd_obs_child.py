@@ -35,8 +35,8 @@ def main() -> int:
 
     import numpy as np
 
-    import jax
     from repro.core import PMVEngine, pagerank
+    from repro.core.mesh import worker_mesh
     from repro.graph import erdos_renyi
     from repro.obs import (
         check_span_nesting,
@@ -52,7 +52,7 @@ def main() -> int:
                              # needs more than one sample against noise
     edges = erdos_renyi(n, 3_000, seed=11)
     spec = pagerank(n)
-    mesh = jax.make_mesh((args.workers,), ("workers",))
+    mesh = worker_mesh(args.workers)
 
     def median_wall(obs):
         eng = PMVEngine(None, store=store_dir, residency="disk",

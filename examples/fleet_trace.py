@@ -30,8 +30,8 @@ import urllib.request
 
 import numpy as np
 
-import jax
 from repro.core import PMVEngine, pagerank
+from repro.core.mesh import worker_mesh
 from repro.faults import FaultPlan, SlowFetch
 from repro.graph import rmat
 from repro.obs import (
@@ -54,7 +54,7 @@ print(f"ingested {len(edges)} edges into {store_dir}")
 
 # -- SPMD solve: W=4 workers, each with its own recorder shard; worker 2's
 #    reads of block 1 are injected 100 ms slower (a failing local disk).
-mesh = jax.make_mesh((W,), ("workers",))
+mesh = worker_mesh(W)
 plan = FaultPlan(events=(SlowFetch(block=1, delay_s=0.1, occurrence=2,
                                    worker=2),), seed=0)
 engine = PMVEngine(None, store=store_dir, residency="disk",

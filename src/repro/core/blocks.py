@@ -32,6 +32,8 @@ from typing import Any
 import jax
 import numpy as np
 
+from repro.kernels.ell_spmv import ell_from_edges, ell_rows, ell_width
+
 __all__ = [
     "BlockEdges",
     "build_stripes",
@@ -121,16 +123,17 @@ def build_stripes(
     e_cap = max(int(counts2d.max()), 1)
 
     # Sort edges by (owner, inner, seg_local) so segment ids are sorted
-    # within each block (enables indices_are_sorted=True downstream).
-    order = np.lexsort((seg_local, inner, owner))
+    # within each block (enables indices_are_sorted=True downstream).  One
+    # stable argsort of the packed int64 key orders exactly as a lexsort of
+    # the three keys.
+    seg_span = int(seg_local.max(initial=0)) + 1
+    order = np.argsort(pair * seg_span + seg_local, kind="stable")
     seg_local = seg_local[order]
     gat_local = gat_local[order]
     ww = None if w is None else w[order]
-    owner_s = owner[order]
-    inner_s = inner[order]
 
     # Split points per (owner, inner) in the sorted order.
-    boundaries = np.searchsorted(owner_s * b + inner_s, np.arange(b * b + 1))
+    boundaries = np.searchsorted(pair[order], np.arange(b * b + 1))
 
     stripes: list[BlockEdges] = []
     for j in range(b):
@@ -176,29 +179,30 @@ def structural_partial_nnz(
 
 @dataclasses.dataclass(frozen=True)
 class EllStripe:
-    """Destination-major ELL repack of a :class:`BlockEdges` stripe for the
-    Pallas kernels (backend='pallas'): each destination row stores up to D
-    source slots; col < 0 marks padding.
+    """ELL repack of a :class:`BlockEdges` stripe for the Pallas kernels
+    (backend='pallas'): each destination row stores up to D source slots;
+    col < 0 marks padding.  Tables are slot-major ([D, rows], the kernel's
+    layout, see kernels.ell_spmv.ops).
 
     Two layouts, produced at pre-partition time (stripe_to_ell):
 
-    - per-block (vertical stripes): cols [b, n_local, D] — row r of table i
-      lists the v^(j)-local sources of destination r in sub-matrix M^(i,j);
-      the kernel runs one table per destination block (partials stay
-      separable for the compact exchange).
-    - merged (horizontal stripes): cols [n_local, D] — all b source blocks'
-      edges of destination r in ONE row, cols pre-offset to index the flat
-      gathered vector [b * stride]; the kernel's combineAll over D is then
-      also the cross-block combineAll, so one kernel call does the whole
-      per-worker compute.
+    - per-block (vertical stripes): cols [b, D, n_local] — column r of table
+      i lists the v^(j)-local sources of destination r in sub-matrix
+      M^(i,j); the kernel runs one table per destination block (partials
+      stay separable for the compact exchange).
+    - merged (horizontal stripes): cols [D, n_local] — all b source blocks'
+      edges of destination r in ONE column, cols pre-offset to index the
+      flat gathered vector [b * stride]; the kernel's combineAll over D is
+      then also the cross-block combineAll, so one kernel call does the
+      whole per-worker compute.
     """
 
-    cols: Any        # [(b,) n_local, D] int32; -1 = pad
+    cols: Any        # [(b,) D, n_local] int32; -1 = pad
     w: Any | None    # matching weights, or None when the spec never reads them
 
     @property
     def d_cap(self) -> int:
-        return self.cols.shape[-1]
+        return self.cols.shape[-2]
 
 
 jax.tree_util.register_dataclass(
@@ -209,10 +213,9 @@ jax.tree_util.register_dataclass(
 
 
 def _pack_ell(dst, src, w, n_rows: int, d_cap: int | None = None):
-    """Edge arrays -> (cols [n_rows, D], w [n_rows, D]); the kernel package's
-    vectorized packer (kernels do not import core, so no cycle)."""
-    from repro.kernels.ell_spmv import ell_from_edges
-
+    """Edge arrays -> slot-major (cols [D, n_rows], w [D, n_rows]); the
+    kernel package's vectorized packer (kernels do not import core, so no
+    cycle)."""
     return ell_from_edges(dst, src, w, n_rows, d_cap=d_cap)
 
 
@@ -225,9 +228,9 @@ def stripe_to_ell(
 ) -> EllStripe:
     """Repack a padded edge-block stripe into ELL neighbor tables.
 
-    merge_col_stride=None: per-block tables [b, n_local, D] (cols are the
+    merge_col_stride=None: per-block tables [b, D, n_local] (cols are the
     block-local gather indices, as stored).  merge_col_stride=s: one merged
-    table [n_local, D] whose cols are flattened to block_k * s + gat_local —
+    table [D, n_local] whose cols are flattened to block_k * s + gat_local —
     the layout ``gathered_gimv``'s flat all-gathered vector wants.
     """
     b, _ = stripe.seg_local.shape
@@ -263,6 +266,7 @@ def stripe_to_ell(
             if cnt:
                 deg = np.bincount(seg[k, :cnt], minlength=n_rows)
                 d_cap = max(d_cap, int(deg.max()))
+        d_cap = ell_width(d_cap)
     tables = [_pack_ell(*block_edges(k), n_rows, d_cap) for k in range(b)]
     cols = np.stack([t[0] for t in tables])
     ww = np.stack([t[1] for t in tables]) if has_w else None
@@ -275,11 +279,9 @@ def stack_ells(ells: list[EllStripe]) -> EllStripe:
     d = max(e.d_cap for e in ells)
 
     def pad(e: EllStripe):
-        extra = d - e.d_cap
-        cols = np.pad(e.cols, [(0, 0)] * (e.cols.ndim - 1) + [(0, extra)],
-                      constant_values=-1)
-        w = None if e.w is None else np.pad(
-            e.w, [(0, 0)] * (e.w.ndim - 1) + [(0, extra)])
+        widths = [(0, 0)] * (e.cols.ndim - 2) + [(0, d - e.d_cap), (0, 0)]
+        cols = np.pad(e.cols, widths, constant_values=-1)
+        w = None if e.w is None else np.pad(e.w, widths)
         return cols, w
 
     padded = [pad(e) for e in ells]
@@ -357,8 +359,9 @@ jax.tree_util.register_dataclass(
 #   skip  — structurally empty blocks, dropped entirely at pack time;
 #   ell   — sparse blocks packed as ROW-BUCKETED ELL slices: destination rows
 #           are grouped by degree into power-of-two buckets, each bucket a
-#           [R_k, D_k] table with its own (much tighter) width, so one skewed
-#           row no longer pads every row of the stripe to d_max;
+#           slot-major [D_k, R_k] table with its own (much tighter) width, so
+#           one skewed row no longer pads every row of the stripe to d_max;
+#           R_k is padded to the kernel's lane tiling (ell_rows);
 #   dense — near-dense blocks materialized as [n_local, n_local] semiring
 #           matrices for the MXU kernel.
 # Rows of every table carry their *flat output index* so same-tactic blocks
@@ -372,20 +375,20 @@ class EllBucket:
     """One degree-bucket ELL slice covering all ell-tactic blocks of a stripe.
 
     rows: [R] int32 flat output row of each table row (-1 = padding row,
-      introduced when stacking workers to a common R); cols: [R, D] int32
-      gather index into the flat source vector (-1 = padding slot); w: [R, D]
-      matching weights or None.  Every destination row lives in exactly ONE
-      bucket (its degree picks it), so bucket results scatter with plain
-      ``set`` — no cross-bucket combine.
+      introduced when stacking workers to a common, lane-aligned R); cols:
+      [D, R] int32 slot-major gather index into the flat source vector (-1 =
+      padding slot); w: [D, R] matching weights or None.  Every destination
+      row lives in exactly ONE bucket (its degree picks it), so bucket
+      results scatter with plain ``set`` — no cross-bucket combine.
     """
 
     rows: Any        # [(b_w,) R] int32; -1 = pad
-    cols: Any        # [(b_w,) R, D] int32; -1 = pad
+    cols: Any        # [(b_w,) D, R] int32; -1 = pad
     w: Any | None    # matching weights, or None
 
     @property
     def d_cap(self) -> int:
-        return self.cols.shape[-1]
+        return self.cols.shape[-2]
 
 
 jax.tree_util.register_dataclass(
@@ -448,7 +451,7 @@ def pack_bucketed_ell(
 
     out_rows[e] is the flat output row of edge e, cols[e] its gather index.
     Each output row with degree d goes to the first bucket whose width
-    boundary >= d; bucket k is packed as a [R_k, boundaries[k]] table.  All
+    boundary >= d; bucket k is packed as a [boundaries[k], R_k] table.  All
     len(boundaries) buckets are emitted (possibly with R_k = 0) so the pytree
     structure is identical across workers; stack_planned drops buckets that
     are empty on every worker.
@@ -474,8 +477,8 @@ def pack_bucketed_ell(
         if rows_k.size == 0:
             buckets.append(EllBucket(
                 rows=np.zeros((0,), np.int32),
-                cols=np.full((0, cap_k), -1, np.int32),
-                w=np.zeros((0, cap_k), np.float32) if has_w else None))
+                cols=np.full((cap_k, 0), -1, np.int32),
+                w=np.zeros((cap_k, 0), np.float32) if has_w else None))
             continue
         remap[:] = -1
         remap[rows_k] = np.arange(rows_k.size)
@@ -565,7 +568,8 @@ def stack_planned(stripes: list[PlannedStripe], semiring: str) -> PlannedStripe:
     """b per-worker planned stripes -> one stripe with a leading worker axis.
 
     Buckets share widths (plan-level boundaries) so only the row counts pad
-    (rows = -1, cols = -1); buckets empty on EVERY worker are dropped.  Dense
+    (rows = -1, cols = -1, to the max over workers at ell_rows alignment);
+    buckets empty on EVERY worker are dropped.  Dense
     groups pad to the max dense-block count with identity-filled matrices
     (index -1 for 'vertical' — dropped at scatter; index 0 for 'merged' —
     the identity-filled columns contribute the combineAll identity)."""
@@ -576,20 +580,13 @@ def stack_planned(stripes: list[PlannedStripe], semiring: str) -> PlannedStripe:
     out_buckets = []
     for k in range(n_buckets):
         bs = [s.buckets[k] for s in stripes]
-        r_max = max(x.rows.shape[0] for x in bs)
+        r_max = ell_rows(max(x.rows.shape[0] for x in bs))
         if r_max == 0:
             continue
-        d = bs[0].cols.shape[-1]
         has_w = bs[0].w is not None
         rows = np.stack([_pad_to(x.rows, r_max, -1) for x in bs])
-        cols = np.stack([
-            np.concatenate([x.cols, np.full((r_max - x.rows.shape[0], d), -1, np.int32)])
-            for x in bs])
-        w = None
-        if has_w:
-            w = np.stack([
-                np.concatenate([x.w, np.zeros((r_max - x.rows.shape[0], d), np.float32)])
-                for x in bs])
+        cols = np.stack([_pad_rows(x.cols, r_max, -1) for x in bs])
+        w = np.stack([_pad_rows(x.w, r_max, 0) for x in bs]) if has_w else None
         out_buckets.append(EllBucket(rows=rows, cols=cols, w=w))
 
     k_max = max((0 if s.dense is None else s.dense.index.shape[0]) for s in stripes)
@@ -635,8 +632,9 @@ def pack_streamed_stripe(
     [b * n_local] output space, this packer keeps a leading destination-block
     axis so ``lax.scan`` can run one block's launches at a time: bucket k is
     rows [b, R_k] (block-LOCAL destination rows, -1 = pad; R_k = the max row
-    count of bucket k over the b blocks) with cols [b, R_k, boundaries[k]]
-    (worker-local sources, -1 = pad).  Dense-tactic blocks keep the
+    count of bucket k over the b blocks, at ell_rows alignment) with
+    slot-major cols [b, boundaries[k], R_k] (worker-local sources, -1 =
+    pad).  Dense-tactic blocks keep the
     'vertical' DenseGroup layout (matrix [k, n_local, n_local], index [k]) —
     they run as per-block MXU launches outside the scan.  rows_out stays
     b * n_local (the flat partial space both schedules feed the exchange
@@ -666,16 +664,10 @@ def pack_streamed_stripe(
     out_buckets = []
     for kk, cap_k in enumerate(boundaries):
         bs = [pb[kk] for pb in per_block]
-        r_max = max(x.rows.shape[0] for x in bs)
+        r_max = ell_rows(max(x.rows.shape[0] for x in bs))
         rows = np.stack([_pad_to(x.rows, r_max, -1) for x in bs])
-        cols = np.stack([
-            np.concatenate([x.cols, np.full((r_max - x.rows.shape[0], cap_k), -1, np.int32)])
-            for x in bs])
-        w = None
-        if has_w:
-            w = np.stack([
-                np.concatenate([x.w, np.zeros((r_max - x.rows.shape[0], cap_k), np.float32)])
-                for x in bs])
+        cols = np.stack([_pad_rows(x.cols, r_max, -1) for x in bs])
+        w = np.stack([_pad_rows(x.w, r_max, 0) for x in bs]) if has_w else None
         out_buckets.append(EllBucket(rows=rows, cols=cols, w=w))
 
     dense = None
@@ -691,9 +683,9 @@ def stack_streamed(
 ) -> PlannedStripe:
     """b per-worker streamed stripes -> one stripe with a worker axis.
 
-    worker_axis=0 stacks bucket arrays [b_w, b, R, D] for shard_map (the
+    worker_axis=0 stacks bucket arrays [b_w, b, D, R] for shard_map (the
     leading axis is what the mesh splits); worker_axis=1 stacks them
-    scan-major [b, b_w, R, D] for emulation mode, so the executor's
+    scan-major [b, b_w, D, R] for emulation mode, so the executor's
     ``lax.scan`` over destination blocks slices the leading axis without a
     whole-table transpose temporary.  Buckets pad R to the cross-worker max
     (rows/cols = -1) and are dropped when empty on EVERY (worker, block);
@@ -706,22 +698,15 @@ def stack_streamed(
     out_buckets = []
     for k in range(n_buckets):
         bs = [s.buckets[k] for s in stripes]
-        r_max = max(x.rows.shape[-1] for x in bs)
+        r_max = ell_rows(max(x.rows.shape[-1] for x in bs))
         if r_max == 0:
             continue
         has_w = bs[0].w is not None
-        rows = np.stack([
-            np.pad(x.rows, ((0, 0), (0, r_max - x.rows.shape[-1])), constant_values=-1)
-            for x in bs], axis=worker_axis)
-        cols = np.stack([
-            np.pad(x.cols, ((0, 0), (0, r_max - x.rows.shape[-1]), (0, 0)),
-                   constant_values=-1)
-            for x in bs], axis=worker_axis)
+        rows = np.stack([_pad_rows(x.rows, r_max, -1) for x in bs], axis=worker_axis)
+        cols = np.stack([_pad_rows(x.cols, r_max, -1) for x in bs], axis=worker_axis)
         w = None
         if has_w:
-            w = np.stack([
-                np.pad(x.w, ((0, 0), (0, r_max - x.rows.shape[-1]), (0, 0)))
-                for x in bs], axis=worker_axis)
+            w = np.stack([_pad_rows(x.w, r_max, 0) for x in bs], axis=worker_axis)
         out_buckets.append(EllBucket(rows=rows, cols=cols, w=w))
 
     k_max = max((0 if s.dense is None else s.dense.index.shape[0]) for s in stripes)
@@ -741,6 +726,12 @@ def stack_streamed(
                          rows_out=stripes[0].rows_out, layout="streamed")
 
 
+def _pad_rows(a: np.ndarray, r: int, fill) -> np.ndarray:
+    """Pad the trailing (row) axis of a bucket array to r entries."""
+    widths = [(0, 0)] * (a.ndim - 1) + [(0, r - a.shape[-1])]
+    return np.pad(a, widths, constant_values=fill)
+
+
 def _dense_nl(stripes: list[PlannedStripe]) -> int:
     for s in stripes:
         if s.dense is not None:
@@ -756,8 +747,8 @@ def planned_to_edges(planned: PlannedStripe) -> tuple[np.ndarray, np.ndarray, np
     has_w = any(b.w is not None for b in planned.buckets)
     for b in planned.buckets:
         rows = np.asarray(b.rows)
-        cols = np.asarray(b.cols)
-        rr = np.repeat(rows, cols.shape[-1]).reshape(cols.shape)
+        cols = np.asarray(b.cols)                    # [D, R]
+        rr = np.broadcast_to(rows[None, :], cols.shape)
         valid = (cols >= 0) & (rr >= 0)
         rows_l.append(rr[valid])
         cols_l.append(cols[valid])

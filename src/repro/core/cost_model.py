@@ -112,8 +112,11 @@ def hybrid_cost(b: int, n: int, stats: GraphStats, theta: float) -> float:
     E[C_hb] = |v| (P_out(θ) + b (1 - P_out(θ)) + 1)
               + 2|v|(b-1) Σ_d (1 - (1 - P_out(θ)/b)^d) p_in(d)
     """
-    p_out = stats.p_out_below(theta)
-    degs, p_in = stats.in_degree_hist()
+    return _hybrid_cost(b, n, stats.p_out_below(theta), *stats.in_degree_hist())
+
+
+def _hybrid_cost(b: int, n: int, p_out: float, degs: np.ndarray,
+                 p_in: np.ndarray) -> float:
     q = 1.0 - p_out / b
     tail = float(np.sum((1.0 - np.power(q, degs)) * p_in))
     return n * (p_out + b * (1.0 - p_out) + 1.0) + 2.0 * n * (b - 1.0) * tail
@@ -133,11 +136,18 @@ def theta_star(
         uniq = stats.out_degree_values().astype(np.float64)
         # thresholds between observed degrees + the two degenerate endpoints
         candidates = np.unique(np.concatenate([[0.0], uniq, uniq + 1.0, [np.inf]]))
+    # the in-degree histogram and the sorted out-degrees are shared by every
+    # candidate: P_out(θ) = (# out-degrees < θ) / n, as stats.p_out_below.
+    degs, p_in = stats.in_degree_hist()
+    out_sorted = np.sort(stats.out_deg)
     best_theta, best_cost = 0.0, np.inf
     for theta in candidates:
-        cost = hybrid_cost(b, n, stats, float(theta))
+        theta = float(theta)
+        p_out = (1.0 if theta == np.inf else
+                 float(np.searchsorted(out_sorted, theta, side="left")) / stats.n)
+        cost = _hybrid_cost(b, n, p_out, degs, p_in)
         if cost < best_cost:
-            best_theta, best_cost = float(theta), cost
+            best_theta, best_cost = theta, cost
     return best_theta, best_cost
 
 

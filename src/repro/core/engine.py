@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core import blocks as blocks_lib
 from repro.core import cost_model, placement, planner, sparse_exchange
 from repro.core.blocks import BlockEdges, DenseRegion
+from repro.core.mesh import as_auto_mesh
 from repro.exchange import plan as exchange_plan
 from repro.kernels.block_gimv import has_semiring, semiring_of
 from repro.core.gimv import GimvSpec
@@ -143,8 +144,6 @@ def make_step(spec: GimvSpec, cfg: StepConfig, mesh: Mesh | None = None, axis_na
             return v_new, delta, stats
         return step
 
-    from jax.experimental.shard_map import shard_map
-
     sharded = P(axis_name)
     repl = P()
     if with_state:
@@ -157,12 +156,12 @@ def make_step(spec: GimvSpec, cfg: StepConfig, mesh: Mesh | None = None, axis_na
             stats = {k: (s if s.ndim == 0 else s) for k, s in stats.items()}
             return v_new[None], delta, stats, xnew[None]
 
-        return shard_map(
+        return jax.shard_map(
             body_state,
             mesh=mesh,
             in_specs=(sharded, sharded, sharded, sharded, sharded),
             out_specs=(sharded, repl, repl, sharded),
-            check_rep=False,
+            check_vma=False,
         )
 
     def body(matrix, v, ctx, mask):
@@ -172,12 +171,12 @@ def make_step(spec: GimvSpec, cfg: StepConfig, mesh: Mesh | None = None, axis_na
         stats = {k: (s if s.ndim == 0 else s) for k, s in stats.items()}
         return v_new[None], delta, stats
 
-    step = shard_map(
+    step = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(sharded, sharded, sharded, sharded),
         out_specs=(sharded, repl, repl),
-        check_rep=False,
+        check_vma=False,
     )
     return step
 
@@ -360,7 +359,7 @@ class PMVEngine:
         self.stream = stream
         self.pallas_interpret = pallas_interpret
         self.base_weights = base_weights
-        self.mesh = mesh
+        self.mesh = as_auto_mesh(mesh)
         self.axis_name = axis_name
         # obs: None/False (the zero-overhead null recorder), True (a fresh
         # repro.obs.Recorder), or a Recorder shared with a server / store.
@@ -493,13 +492,15 @@ class PMVEngine:
                 ),
             }
             capacity = self._capacity(pm, hm)
-            if backend in ("pallas", "planned"):
+            if backend == "pallas" or (backend == "planned" and hm.dense_nnz > 0):
                 semiring = semiring_of(spec.combine2, spec.combine_all)
                 if backend == "pallas":
                     matrix["sparse_ell"] = blocks_lib.stack_ells([
                         blocks_lib.stripe_to_ell(s, part.n_local) for s in hm.sparse_vertical])
                 # the dense REGION is a region-level dense tactic (§3.5):
-                # both kernel modes run it as a materialized MXU matmul
+                # both kernel modes run it as a materialized MXU matmul.  The
+                # planned mode skips an empty region (θ above every
+                # out-degree), whose stripes hold no edge to run.
                 matrix["dense_matrix"] = np.stack([
                     blocks_lib.materialize_dense_matrix(
                         s, part.n_local, hm.dense.d_cap, semiring)

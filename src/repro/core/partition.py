@@ -12,6 +12,8 @@ O(|M|) shuffle; afterwards only vectors cross the interconnect.
 from __future__ import annotations
 
 import dataclasses
+import functools
+from typing import Callable
 
 import numpy as np
 
@@ -99,15 +101,29 @@ class Partition:
 
 @dataclasses.dataclass(frozen=True)
 class PartitionedMatrix:
-    """Pre-partitioned matrix for one basic placement."""
+    """Pre-partitioned matrix for the basic placements.
+
+    The stripes of each placement are built by ``stripes(placement)`` on
+    first access: a solve reads one placement (hybrid reads neither), and
+    each set costs a sort of every edge.
+    """
 
     part: Partition
     stats: GraphStats
-    vertical: list          # b stripes: inner axis = dst block i, gat = v^(j) local
-    horizontal: list        # b stripes: inner axis = src block jj, gat = v_all[jj]
     block_nnz: np.ndarray   # [b, b] edges in M^(i,j)
     partial_nnz: np.ndarray  # [b, b] structural |v^(i,j)|
     partial_cap: int        # max structural partial size (static exchange cap)
+    stripes: Callable[[str], list] = dataclasses.field(repr=False, compare=False)
+
+    @functools.cached_property
+    def vertical(self) -> list:
+        """b stripes: inner axis = dst block i, gat = v^(j) local."""
+        return self.stripes("vertical")
+
+    @functools.cached_property
+    def horizontal(self) -> list:
+        """b stripes: inner axis = src block jj, gat = v_all[jj]."""
+        return self.stripes("horizontal")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -196,21 +212,23 @@ def partition_graph(
     src, dst = edges[:, 0], edges[:, 1]
     w = _edge_weights(spec, stats.out_deg, src, base_weights)
 
-    sb, sl = part.block_of(src), part.local_of(src)
+    sb = part.block_of(src)
     db, dl = part.block_of(dst), part.local_of(dst)
 
-    vertical, nnz_v = blocks_lib.build_stripes(db, dl, sb, sl, w, b, stripe_axis="gat")
-    horizontal, nnz_h = blocks_lib.build_stripes(db, dl, sb, sl, w, b, stripe_axis="seg")
-    assert (nnz_v == nnz_h).all()
+    def stripes(placement: str) -> list:
+        axis = "gat" if placement == "vertical" else "seg"
+        return blocks_lib.build_stripes(
+            part.block_of(dst), part.local_of(dst), part.block_of(src),
+            part.local_of(src), w, b, stripe_axis=axis)[0]
+
     partial_nnz = blocks_lib.structural_partial_nnz(db, dl, sb, b)
     pm = PartitionedMatrix(
         part=part,
         stats=stats,
-        vertical=vertical,
-        horizontal=horizontal,
-        block_nnz=nnz_v,
+        block_nnz=np.bincount(db * b + sb, minlength=b * b).reshape(b, b),
         partial_nnz=partial_nnz,
         partial_cap=max(int(partial_nnz.max()), 1),
+        stripes=stripes,
     )
 
     hm = None

@@ -272,9 +272,10 @@ def gathered_gimv(spec: GimvSpec, stripe: BlockEdges, v_all: jnp.ndarray, n_loca
 # --------------------------------------------------------------------------
 
 def ell_gimv_call(spec: GimvSpec, cols, w, v, interpret: bool):
-    """Dispatch one ELL table to the (multi-)query semiring kernel.
+    """Dispatch slot-major ELL tables to the (multi-)query semiring kernel.
 
-    cols/w: [R, D]; v: [N] or [N, Q] -> r: [R] or [R, Q]."""
+    cols/w: [*L, D, R] (leading axes batch tables into one launch); v: [N]
+    or [N, Q] -> r: [*L, R] or [*L, R, Q]."""
     semiring = semiring_of(spec.combine2, spec.combine_all)
     if not spec.needs_weights:
         w = None
@@ -288,16 +289,14 @@ def _ell_gathered_gimv(spec: GimvSpec, ell: EllStripe, v_local, n_local: int,
     """Pallas analog of the horizontal compute: one merged ELL table per
     worker (cols pre-offset into the flat gathered vector), one kernel call.
 
-    Emulation mode folds the worker axis into the row axis — the merged cols
-    already index the flat blocked vector, which IS v_local.reshape(b * n_local).
-    Returns r [n_local(, Q)] (emulation: [b, n_local(, Q)])."""
+    Emulation mode batches the workers' tables into one launch — the merged
+    cols already index the flat blocked vector, which IS
+    v_local.reshape(b * n_local).  Returns r [n_local(, Q)] (emulation:
+    [b, n_local(, Q)])."""
     if axis_name is None:
         b = v_local.shape[0]
         v_flat = v_local.reshape((b * n_local,) + v_local.shape[2:])
-        cols = ell.cols.reshape((-1,) + ell.cols.shape[-1:])
-        w = None if ell.w is None else ell.w.reshape(cols.shape)
-        r_flat = ell_gimv_call(spec, cols, w, v_flat, interpret)
-        return r_flat.reshape((b, n_local) + r_flat.shape[1:])
+        return ell_gimv_call(spec, ell.cols, ell.w, v_flat, interpret)
     v_all = _all_gather(v_local, axis_name)          # [b, n_local(, Q)]
     v_flat = v_all.reshape((-1,) + v_all.shape[2:])  # [b*n_local(, Q)]
     return ell_gimv_call(spec, ell.cols, ell.w, v_flat, interpret)
@@ -310,19 +309,12 @@ def _ell_block_partials(spec: GimvSpec, ell: EllStripe, v_local, n_local: int,
     offsetting cols into the flat per-worker vector.  Returns partials
     [b, n_local(, Q)] (emulation: [b_worker, b, n_local(, Q)])."""
     if axis_name is None:
-        b_w, b = ell.cols.shape[0], ell.cols.shape[1]
+        b_w = ell.cols.shape[0]
         off = (jnp.arange(b_w, dtype=jnp.int32) * n_local)[:, None, None, None]
         cols = jnp.where(ell.cols >= 0, ell.cols + off, -1)
-        cols2 = cols.reshape(b_w * b * n_local, -1)
-        w2 = None if ell.w is None else ell.w.reshape(cols2.shape)
         v_flat = v_local.reshape((b_w * n_local,) + v_local.shape[2:])
-        r = ell_gimv_call(spec, cols2, w2, v_flat, interpret)
-        return r.reshape((b_w, b, n_local) + r.shape[1:])
-    b = ell.cols.shape[0]
-    cols2 = ell.cols.reshape(b * n_local, -1)
-    w2 = None if ell.w is None else ell.w.reshape(cols2.shape)
-    r = ell_gimv_call(spec, cols2, w2, v_local, interpret)
-    return r.reshape((b, n_local) + r.shape[1:])
+        return ell_gimv_call(spec, cols, ell.w, v_flat, interpret)
+    return ell_gimv_call(spec, ell.cols, ell.w, v_local, interpret)
 
 
 def _ell_partials_compact(spec: GimvSpec, ell: EllStripe, v_local, n_local: int,
@@ -338,16 +330,13 @@ def _ell_partials_compact(spec: GimvSpec, ell: EllStripe, v_local, n_local: int,
         b_w = ell.cols.shape[0]
         off = (jnp.arange(b_w, dtype=jnp.int32) * n_local)[:, None, None]
         v_flat = v_local.reshape((b_w * n_local,) + v_local.shape[2:])
-        cols_s = jnp.swapaxes(ell.cols, 0, 1)    # [b, b_w, n_local, D]
+        cols_s = jnp.swapaxes(ell.cols, 0, 1)    # [b, b_w, D, n_local]
         w_s = None if ell.w is None else jnp.swapaxes(ell.w, 0, 1)
 
         def body(_, blk):
-            cols, w = blk                        # [b_w, n_local, D]
+            cols, w = blk                        # [b_w, D, n_local]
             cols = jnp.where(cols >= 0, cols + off, -1)
-            cols2 = cols.reshape(b_w * n_local, -1)
-            w2 = None if w is None else w.reshape(cols2.shape)
-            r = ell_gimv_call(spec, cols2, w2, v_flat, interpret)
-            partial_ = r.reshape((b_w, n_local) + r.shape[1:])
+            partial_ = ell_gimv_call(spec, cols, w, v_flat, interpret)
             return None, sparse_exchange.compact_partials(
                 spec, partial_, capacity, None, batched=batched)
 
@@ -357,7 +346,7 @@ def _ell_partials_compact(spec: GimvSpec, ell: EllStripe, v_local, n_local: int,
         return idx, val, jnp.sum(over), jnp.sum(logical)
 
     def body(_, blk):
-        cols, w = blk                            # [n_local, D]
+        cols, w = blk                            # [D, n_local]
         r = ell_gimv_call(spec, cols, w, v_local, interpret)
         return None, sparse_exchange.compact_partials(
             spec, r, capacity, None, batched=batched)
@@ -377,17 +366,14 @@ def _ell_partials_payload(spec: GimvSpec, ell: EllStripe, v_local, n_local: int,
         b_w = ell.cols.shape[0]
         off = (jnp.arange(b_w, dtype=jnp.int32) * n_local)[:, None, None]
         v_flat = v_local.reshape((b_w * n_local,) + v_local.shape[2:])
-        cols_s = jnp.swapaxes(ell.cols, 0, 1)    # [b, b_w, n_local, D]
+        cols_s = jnp.swapaxes(ell.cols, 0, 1)    # [b, b_w, D, n_local]
         w_s = None if ell.w is None else jnp.swapaxes(ell.w, 0, 1)
         srows_s = jnp.swapaxes(send_rows, 0, 1)  # [b, b_w, p]
 
         def body(_, blk):
-            cols, w, srows = blk                 # [b_w, n_local, D] / [b_w, p]
+            cols, w, srows = blk                 # [b_w, D, n_local] / [b_w, p]
             cols = jnp.where(cols >= 0, cols + off, -1)
-            cols2 = cols.reshape(b_w * n_local, -1)
-            w2 = None if w is None else w.reshape(cols2.shape)
-            r = ell_gimv_call(spec, cols2, w2, v_flat, interpret)
-            partial_ = r.reshape((b_w, n_local) + r.shape[1:])
+            partial_ = ell_gimv_call(spec, cols, w, v_flat, interpret)
             pay = packed_rt.gather_payload(spec, partial_, srows)
             return None, (pay, sparse_exchange.count_non_identity(spec, pay))
 
@@ -395,7 +381,7 @@ def _ell_partials_payload(spec: GimvSpec, ell: EllStripe, v_local, n_local: int,
         return jnp.swapaxes(val, 0, 1), jnp.sum(logical)
 
     def body(_, blk):
-        cols, w, srows = blk                     # [n_local, D] / [p]
+        cols, w, srows = blk                     # [D, n_local] / [p]
         r = ell_gimv_call(spec, cols, w, v_local, interpret)
         pay = packed_rt.gather_payload(spec, r, srows)
         return None, (pay, sparse_exchange.count_non_identity(spec, pay))
@@ -473,10 +459,8 @@ def _planned_merged_gimv(spec: GimvSpec, planned: PlannedStripe, v_local,
         woff = (jnp.arange(b_w, dtype=jnp.int32) * n_local)[:, None]
         for bucket in planned.buckets:
             rows = jnp.where(bucket.rows >= 0, bucket.rows + woff, -1).reshape(-1)
-            cols2 = bucket.cols.reshape((-1,) + bucket.cols.shape[-1:])
-            w2 = None if bucket.w is None else bucket.w.reshape(cols2.shape)
-            r = ell_gimv_call(spec, cols2, w2, v_flat, interpret)
-            out = _scatter_set(out, rows, r, drop)
+            r = ell_gimv_call(spec, bucket.cols, bucket.w, v_flat, interpret)
+            out = _scatter_set(out, rows, r.reshape((-1,) + tail), drop)
         r_all = out[:drop].reshape((b_w, n_local) + tail)
         if planned.dense is not None:
             k = planned.dense.index.shape[-1]
@@ -521,11 +505,9 @@ def _planned_vertical_partials(spec: GimvSpec, planned: PlannedStripe, v_local,
         roff = (jnp.arange(b_w, dtype=jnp.int32) * planned.rows_out)[:, None]
         for bucket in planned.buckets:
             cols = jnp.where(bucket.cols >= 0, bucket.cols + coff, -1)
-            cols2 = cols.reshape((-1,) + cols.shape[-1:])
-            w2 = None if bucket.w is None else bucket.w.reshape(cols2.shape)
             rows = jnp.where(bucket.rows >= 0, bucket.rows + roff, -1).reshape(-1)
-            r = ell_gimv_call(spec, cols2, w2, v_flat, interpret)
-            out = _scatter_set(out, rows, r, drop)
+            r = ell_gimv_call(spec, cols, bucket.w, v_flat, interpret)
+            out = _scatter_set(out, rows, r.reshape((-1,) + tail), drop)
         if planned.dense is not None:
             k = planned.dense.index.shape[-1]
             ar = jnp.arange(n_local, dtype=jnp.int32)[None, :]
@@ -594,13 +576,11 @@ def _streamed_planned_compact(spec: GimvSpec, streamed: PlannedStripe, v_local,
 
         def body(_, bks):
             out = jnp.full((drop + 1,) + tail, ident, spec.dtype)
-            for rows, cols, w in bks:            # [b_w, R(, D)] per bucket
+            for rows, cols, w in bks:            # [b_w(, D), R] per bucket
                 cols2 = jnp.where(cols >= 0, cols + coff, -1)
-                cols2 = cols2.reshape((-1,) + cols2.shape[-1:])
-                w2 = None if w is None else w.reshape(cols2.shape)
                 rows2 = jnp.where(rows >= 0, rows + roff, -1).reshape(-1)
-                r = ell_gimv_call(spec, cols2, w2, v_flat, interpret)
-                out = _scatter_set(out, rows2, r, drop)
+                r = ell_gimv_call(spec, cols2, w, v_flat, interpret)
+                out = _scatter_set(out, rows2, r.reshape((-1,) + tail), drop)
             partial_ = out[:drop].reshape((b_w, n_local) + tail)
             return None, sparse_exchange.compact_chunk(
                 spec, partial_, capacity, batched=batched)
@@ -625,7 +605,7 @@ def _streamed_planned_compact(spec: GimvSpec, streamed: PlannedStripe, v_local,
 
     def body(_, bks):
         out = jnp.full((n_local + 1,) + v_local.shape[1:], ident, spec.dtype)
-        for rows, cols, w in bks:                # [R(, D)] per bucket
+        for rows, cols, w in bks:                # [(D,) R] per bucket
             r = ell_gimv_call(spec, cols, w, v_local, interpret)
             out = _scatter_set(out, rows, r, n_local)
         return None, sparse_exchange.compact_chunk(
@@ -675,13 +655,11 @@ def _streamed_planned_payload(spec: GimvSpec, streamed: PlannedStripe, v_local,
         def body(_, xs_):
             bks, srows = xs_
             out = jnp.full((drop + 1,) + tail, ident, spec.dtype)
-            for rows, cols, w in bks:            # [b_w, R(, D)] per bucket
+            for rows, cols, w in bks:            # [b_w(, D), R] per bucket
                 cols2 = jnp.where(cols >= 0, cols + coff, -1)
-                cols2 = cols2.reshape((-1,) + cols2.shape[-1:])
-                w2 = None if w is None else w.reshape(cols2.shape)
                 rows2 = jnp.where(rows >= 0, rows + roff, -1).reshape(-1)
-                r = ell_gimv_call(spec, cols2, w2, v_flat, interpret)
-                out = _scatter_set(out, rows2, r, drop)
+                r = ell_gimv_call(spec, cols2, w, v_flat, interpret)
+                out = _scatter_set(out, rows2, r.reshape((-1,) + tail), drop)
             partial_ = out[:drop].reshape((b_w, n_local) + tail)
             pay = packed_rt.gather_payload(spec, partial_, srows)
             return None, (pay, sparse_exchange.count_non_identity(spec, pay))
@@ -708,7 +686,7 @@ def _streamed_planned_payload(spec: GimvSpec, streamed: PlannedStripe, v_local,
     def body(_, xs_):
         bks, srows = xs_
         out = jnp.full((n_local + 1,) + v_local.shape[1:], ident, spec.dtype)
-        for rows, cols, w in bks:                # [R(, D)] per bucket
+        for rows, cols, w in bks:                # [(D,) R] per bucket
             r = ell_gimv_call(spec, cols, w, v_local, interpret)
             out = _scatter_set(out, rows, r, n_local)
         pay = packed_rt.gather_payload(spec, out[:n_local], srows)

@@ -47,6 +47,7 @@ import numpy as np
 from repro.core import cost_model
 from repro.core.blocks import BlockEdges
 from repro.core.sparse_exchange import SCATTER_METHODS
+from repro.kernels.ell_spmv import ell_width
 
 __all__ = [
     "BlockPlan",
@@ -63,6 +64,11 @@ __all__ = [
 ]
 
 TACTICS = ("skip", "ell", "dense")
+# Power-of-two ELL widths 1 .. 2^15: a Graph500 scale-22 graph's per-block
+# in-degrees stay below that, so no bucket drops from the narrow end, where
+# the bulk of rows (in-degree 1-2 within a block) would pad to the narrowest
+# kept width.
+MAX_BUCKETS = 16
 MODES = ("xla", "pallas", "planned")
 STREAM_MODES = ("on", "off")
 RESIDENCY_MODES = cost_model.RESIDENCY_MODES
@@ -226,16 +232,17 @@ class ExecutionPlan:
         return sum(bp.cost for bp in self.blocks)
 
 
-def bucket_boundaries(d_max: int, *, max_buckets: int = 8) -> tuple[int, ...]:
+def bucket_boundaries(d_max: int, *, max_buckets: int = MAX_BUCKETS) -> tuple[int, ...]:
     """Power-of-two ELL bucket widths up to d_max, capped at max_buckets
     (dropping from the narrow end: low-degree rows then land in the smallest
-    remaining boundary, still correct, just slightly more padded)."""
+    remaining boundary, still correct, just slightly more padded).  The
+    widest is d_max at the kernel's slot alignment (``ell_width``)."""
     bounds = []
     d = 1
     while d < max(d_max, 1):
         bounds.append(d)
         d *= 2
-    bounds.append(max(d_max, 1))
+    bounds.append(ell_width(d_max))
     return tuple(bounds[-max_buckets:])
 
 
@@ -347,7 +354,7 @@ def plan_execution(
     capacity: int | None = None,
     scatter: str = "auto",
     stream: str = "off",
-    max_buckets: int = 8,
+    max_buckets: int = MAX_BUCKETS,
     mxu_advantage: float = cost_model.MXU_SLOT_ADVANTAGE,
     interpret: bool = False,
     residency: str = "device",
@@ -396,7 +403,7 @@ def plan_from_stats(
     capacity: int | None = None,
     scatter: str = "auto",
     stream: str = "off",
-    max_buckets: int = 8,
+    max_buckets: int = MAX_BUCKETS,
     mxu_advantage: float = cost_model.MXU_SLOT_ADVANTAGE,
     interpret: bool = False,
     residency: str = "device",

@@ -62,13 +62,21 @@ def rmat(
     assert abs(a + b + c + d - 1.0) < 1e-9
     rng = np.random.default_rng(seed)
     n = 1 << log2_n
-    probs = np.array([a, b, c, d])
+    # The quadrant draw is rng.choice(4, p=[a, b, c, d]) unrolled: choice
+    # counts the normalized cdf entries <= one uniform draw, and three uint8
+    # compares do the same on the same stream.
+    cdf = np.array([a, b, c, d]).cumsum()
+    cdf /= cdf[-1]
     dst = np.zeros(n_edges, dtype=np.int64)
     src = np.zeros(n_edges, dtype=np.int64)
     for _ in range(log2_n):
-        quad = rng.choice(4, size=n_edges, p=probs)
-        dst = (dst << 1) | (quad >> 1)
-        src = (src << 1) | (quad & 1)
+        u = rng.random(n_edges)
+        quad = ((u >= cdf[0]).view(np.uint8) + (u >= cdf[1]).view(np.uint8)
+                + (u >= cdf[2]).view(np.uint8))
+        dst <<= 1
+        dst |= quad >> 1
+        src <<= 1
+        src |= quad & 1
     edges = np.stack([src, dst], axis=1)
     if remove_self_loops:
         edges = edges[edges[:, 0] != edges[:, 1]]

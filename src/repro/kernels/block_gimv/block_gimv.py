@@ -43,6 +43,17 @@ def _identity(semiring: str, dtype):
     return jnp.array(-jnp.inf if jnp.issubdtype(dtype, jnp.floating) else jnp.iinfo(dtype).min, dtype)
 
 
+def _dense_tropical_part(semiring: str, m, v_row, out_dtype):
+    """(TM, 1) partial combineAll of a (TM, TK) tile against a (1, TK) row."""
+    if semiring == "min_plus":
+        return jnp.min(m + v_row, axis=1, keepdims=True)
+    if semiring == "max_plus":
+        return jnp.max(m + v_row, axis=1, keepdims=True)
+    # min_src: m is a presence indicator; absent -> identity
+    x = jnp.where(m > 0, v_row.astype(out_dtype), _identity(semiring, out_dtype))
+    return jnp.min(x, axis=1, keepdims=True)
+
+
 def _dense_gimv_kernel(m_ref, v_ref, o_ref, *, semiring: str):
     """One (TM, TK) tile: partial combineAll over the TK columns."""
     k = pl.program_id(1)
@@ -53,16 +64,11 @@ def _dense_gimv_kernel(m_ref, v_ref, o_ref, *, semiring: str):
         part = jax.lax.dot_general(
             m, v,
             dimension_numbers=(((1,), (1,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=o_ref.dtype,
-        )                               # (TM, 1) — MXU
-    elif semiring == "min_plus":
-        part = jnp.min(m + v, axis=1, keepdims=True)
-    elif semiring == "max_plus":
-        part = jnp.max(m + v, axis=1, keepdims=True)
-    else:  # min_src: m is a presence indicator; absent -> identity
-        ident = _identity(semiring, o_ref.dtype)
-        x = jnp.where(m > 0, v.astype(o_ref.dtype), ident)
-        part = jnp.min(x, axis=1, keepdims=True)
+        )                               # (TM, 1) — MXU, f32 passes
+    else:
+        part = _dense_tropical_part(semiring, m, v, o_ref.dtype)
 
     @pl.when(k == 0)
     def _init():
@@ -74,38 +80,45 @@ def _dense_gimv_kernel(m_ref, v_ref, o_ref, *, semiring: str):
 
 
 def _dense_gimv_multi_kernel(m_ref, v_ref, o_ref, *, semiring: str):
-    """One (TM, TK) x (TK, TQ) tile: partial combineAll over the TK columns.
+    """One (TM, TK) matrix tile against TQ queries: partial combineAll over
+    the TK columns.
 
-    plus_times is a straight MXU matmul; the tropical semirings broadcast to
-    a (TM, TK, TQ) tile in VMEM and reduce on the VPU — ops.py keeps TQ small
-    for those so the 3-D temporary fits.
+    plus_times is a straight MXU matmul of the (TK, TQ) query tile.  The
+    tropical semirings read the TRANSPOSED (TQ, TK) tile and reduce one
+    query row at a time into output column q, the single-query pattern: a
+    (TM, TK, TQ) broadcast would need a lane-to-sublane relayout.
     """
     k = pl.program_id(2)
     m = m_ref[...]                      # (TM, TK) matrix values
-    v = v_ref[...]                      # (TK, TQ) query-tile of vectors
 
     if semiring == "plus_times":
         part = jax.lax.dot_general(
-            m, v,
+            m, v_ref[...],
             dimension_numbers=(((1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
             preferred_element_type=o_ref.dtype,
         )                               # (TM, TQ) — MXU at full width
-    elif semiring == "min_plus":
-        part = jnp.min(m[:, :, None] + v[None, :, :], axis=1)
-    elif semiring == "max_plus":
-        part = jnp.max(m[:, :, None] + v[None, :, :], axis=1)
-    else:  # min_src: m is a presence indicator; absent -> identity
-        ident = _identity(semiring, o_ref.dtype)
-        x = jnp.where(m[:, :, None] > 0, v[None, :, :].astype(o_ref.dtype), ident)
-        part = jnp.min(x, axis=1)
 
-    @pl.when(k == 0)
-    def _init():
-        o_ref[...] = part.astype(o_ref.dtype)
+        @pl.when(k == 0)
+        def _init():
+            o_ref[...] = part.astype(o_ref.dtype)
 
-    @pl.when(k != 0)
-    def _acc():
-        o_ref[...] = _combine_all(semiring, o_ref[...], part.astype(o_ref.dtype))
+        @pl.when(k != 0)
+        def _acc():
+            o_ref[...] = o_ref[...] + part.astype(o_ref.dtype)
+        return
+
+    for q in range(v_ref.shape[0]):
+        part = _dense_tropical_part(semiring, m, v_ref[q:q + 1, :], o_ref.dtype)
+
+        @pl.when(k == 0)
+        def _init():
+            o_ref[:, q:q + 1] = part.astype(o_ref.dtype)
+
+        @pl.when(k != 0)
+        def _acc():
+            o_ref[:, q:q + 1] = _combine_all(semiring, o_ref[:, q:q + 1],
+                                             part.astype(o_ref.dtype))
 
 
 def dense_gimv_multi_pallas(
@@ -125,7 +138,8 @@ def dense_gimv_multi_pallas(
     per column.  The grid gains a query-tile axis so the MXU (plus_times) /
     VPU (tropical) is fed TQ queries wide per pass over the resident matrix
     tile — the batched-serving analog of dense_gimv_pallas.  M, K, Q must be
-    multiples of the tile sizes (ops.py pads).  Returns r: [M, Q].
+    multiples of the tile sizes (ops.py pads); tile_q is the full Q or a
+    multiple of 128 (lane blocks).  Returns r: [M, Q].
     """
     assert semiring in SEMIRINGS, semiring
     M, K = m.shape
@@ -136,12 +150,16 @@ def dense_gimv_multi_pallas(
     out_dtype = out_dtype or v.dtype
 
     grid = (M // tile_m, Q // tile_q, K // tile_k)  # k innermost: accumulate
+    if semiring == "plus_times":
+        v_spec = pl.BlockSpec((tile_k, tile_q), lambda i, q, k: (k, q))
+    else:
+        v, v_spec = v.T, pl.BlockSpec((tile_q, tile_k), lambda i, q, k: (q, k))
     return pl.pallas_call(
         functools.partial(_dense_gimv_multi_kernel, semiring=semiring),
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile_m, tile_k), lambda i, q, k: (i, k)),
-            pl.BlockSpec((tile_k, tile_q), lambda i, q, k: (k, q)),
+            v_spec,
         ],
         out_specs=pl.BlockSpec((tile_m, tile_q), lambda i, q, k: (i, q)),
         out_shape=jax.ShapeDtypeStruct((M, Q), out_dtype),

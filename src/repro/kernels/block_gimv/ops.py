@@ -58,12 +58,12 @@ def dense_gimv_multi(
     """Multi-query dense block GIM-V with automatic tile padding.
 
     m: [M, K], v: [K, Q] -> r: [M, Q].  plus_times defaults to a 128-wide
-    query tile (full MXU); the tropical semirings default to TQ=8 so their
-    (TM, TK, TQ) broadcast temporary stays ~512 KB of VMEM.
+    query tile (full MXU); the tropical semirings, which reduce one query
+    column at a time, take the whole query axis up to 128 queries.
     """
     assert semiring in SEMIRINGS
     if tile_q is None:
-        tile_q = 128 if semiring == "plus_times" else 8
+        tile_q = 128 if semiring == "plus_times" or v.shape[1] > 128 else v.shape[1]
     M, K = m.shape
     _, Q = v.shape
     Mp = -(-M // tile_m) * tile_m

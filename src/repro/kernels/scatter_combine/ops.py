@@ -46,7 +46,22 @@ def scatter_combine_gimv(
     return out[:n_out]
 
 
-@partial(jax.jit, static_argnames=("n_out", "semiring", "tile_n", "tile_t", "tile_q", "interpret"))
+def _query_tile(Q: int) -> tuple[int, int]:
+    """(tile_q, Qp): the whole query axis as one lane block up to 128
+    queries (a block's minor dim must be the full dim or a multiple of 128),
+    128-wide tiles beyond."""
+    if Q <= 128:
+        return Q, Q
+    return 128, -(-Q // 128) * 128
+
+
+def _packed_tile_t(width: int) -> int:
+    """Slot tile of the packed kernels: 128 words of 32/width ids each, so
+    the word block is one full 128-lane row."""
+    return 128 * (32 // width)
+
+
+@partial(jax.jit, static_argnames=("n_out", "semiring", "tile_n", "tile_t", "interpret"))
 def scatter_combine_gimv_multi(
     idx: jnp.ndarray,
     val: jnp.ndarray,
@@ -55,7 +70,6 @@ def scatter_combine_gimv_multi(
     semiring: str,
     tile_n: int = 128,
     tile_t: int = 128,
-    tile_q: int = 8,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Multi-query scatter-combine with automatic tile padding.
@@ -65,7 +79,7 @@ def scatter_combine_gimv_multi(
     T, Q = val.shape
     Tp = max(-(-T // tile_t) * tile_t, tile_t)
     Np = -(-n_out // tile_n) * tile_n
-    Qp = -(-Q // tile_q) * tile_q
+    tile_q, Qp = _query_tile(Q)
     if Tp != T:
         idx = jnp.pad(idx, (0, Tp - T), constant_values=-1)
         val = jnp.pad(val, ((0, Tp - T), (0, 0)))
@@ -78,7 +92,7 @@ def scatter_combine_gimv_multi(
 
 
 @partial(jax.jit, static_argnames=("n_out", "set_slots", "n_local", "width",
-                                   "semiring", "tile_n", "tile_t", "interpret"))
+                                   "semiring", "tile_n", "interpret"))
 def packed_scatter_combine_gimv(
     words: jnp.ndarray,
     val: jnp.ndarray,
@@ -89,7 +103,6 @@ def packed_scatter_combine_gimv(
     width: int,
     semiring: str,
     tile_n: int = 128,
-    tile_t: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Indexed-payload scatter-combine with automatic tile padding.
@@ -104,6 +117,7 @@ def packed_scatter_combine_gimv(
     assert semiring in SEMIRINGS
     (T,) = val.shape
     k = 32 // width
+    tile_t = _packed_tile_t(width)
     Tp = max(-(-T // tile_t) * tile_t, tile_t)
     Np = -(-n_out // tile_n) * tile_n
     if Tp != T:
@@ -117,8 +131,7 @@ def packed_scatter_combine_gimv(
 
 
 @partial(jax.jit, static_argnames=("n_out", "set_slots", "n_local", "width",
-                                   "semiring", "tile_n", "tile_t", "tile_q",
-                                   "interpret"))
+                                   "semiring", "tile_n", "interpret"))
 def packed_scatter_combine_gimv_multi(
     words: jnp.ndarray,
     val: jnp.ndarray,
@@ -129,8 +142,6 @@ def packed_scatter_combine_gimv_multi(
     width: int,
     semiring: str,
     tile_n: int = 128,
-    tile_t: int = 128,
-    tile_q: int = 8,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Multi-query indexed-payload scatter-combine with tile padding.
@@ -139,9 +150,10 @@ def packed_scatter_combine_gimv_multi(
     assert semiring in SEMIRINGS
     T, Q = val.shape
     k = 32 // width
+    tile_t = _packed_tile_t(width)
     Tp = max(-(-T // tile_t) * tile_t, tile_t)
     Np = -(-n_out // tile_n) * tile_n
-    Qp = -(-Q // tile_q) * tile_q
+    tile_q, Qp = _query_tile(Q)
     if Tp != T:
         words = jnp.pad(words, (0, (Tp - T) // k))
         val = jnp.pad(val, ((0, Tp - T), (0, 0)))

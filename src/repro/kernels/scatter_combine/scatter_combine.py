@@ -194,6 +194,61 @@ def packed_scatter_combine_pallas(
     return out[:, 0]
 
 
+def _tropical_columns(semiring: str, onehot, val_t_ref, o_ref, t) -> None:
+    """Tropical multi-query combine, one query column at a time.
+
+    ``val_t_ref`` holds the TRANSPOSED (TQ, TI) payload tile: each query is a
+    (1, TI) row, masked by the (TN, TI) one-hot and reduced along lanes into
+    output column q — the single-query kernel's pattern.  A (TN, TI, TQ)
+    broadcast would need a lane-to-sublane relayout Mosaic does not lower."""
+    ident = _identity(semiring, o_ref.dtype)
+    reduce = jnp.min if semiring in ("min_plus", "min_src") else jnp.max
+    for q in range(val_t_ref.shape[0]):
+        x = jnp.where(onehot, val_t_ref[q:q + 1, :].astype(o_ref.dtype), ident)
+        part = reduce(x, axis=1, keepdims=True)             # (TN, 1)
+
+        @pl.when(t == 0)
+        def _init():
+            o_ref[:, q:q + 1] = part
+
+        @pl.when(t != 0)
+        def _acc():
+            o_ref[:, q:q + 1] = _combine_all(semiring, o_ref[:, q:q + 1], part)
+
+
+def _plus_times_columns(onehot, val_ref, o_ref, t) -> None:
+    """plus_times multi-query combine: one (TN, TI) x (TI, TQ) MXU matmul."""
+    part = jax.lax.dot_general(
+        onehot.astype(o_ref.dtype), val_ref[...].astype(o_ref.dtype),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=o_ref.dtype,
+    )                                        # (TN, TQ) — MXU at full width
+
+    @pl.when(t == 0)
+    def _init():
+        o_ref[...] = part
+
+    @pl.when(t != 0)
+    def _acc():
+        o_ref[...] = o_ref[...] + part
+
+
+def _multi_combine(semiring: str, onehot, val_ref, o_ref, t) -> None:
+    if semiring == "plus_times":
+        _plus_times_columns(onehot, val_ref, o_ref, t)
+    else:
+        _tropical_columns(semiring, onehot, val_ref, o_ref, t)
+
+
+def _multi_val_spec(semiring: str, tile_t: int, tile_q: int):
+    """(transform, BlockSpec) of the [T, Q] payload for the multi kernels:
+    plus_times reads (TI, TQ) tiles for the matmul, the tropical semirings
+    read the transposed [Q, T] payload as (TQ, TI) tiles."""
+    if semiring == "plus_times":
+        return (lambda v: v), pl.BlockSpec((tile_t, tile_q), lambda i, q, t: (t, q))
+    return (lambda v: v.T), pl.BlockSpec((tile_q, tile_t), lambda i, q, t: (q, t))
+
+
 def _packed_scatter_multi_kernel(w_ref, val_ref, o_ref, *, semiring: str,
                                  tile_n: int, tile_t: int, width: int,
                                  set_slots: int, n_local: int):
@@ -202,29 +257,7 @@ def _packed_scatter_multi_kernel(w_ref, val_ref, o_ref, *, semiring: str,
     idx = _decode_packed_ids(w_ref, t, width=width, tile_t=tile_t,
                              set_slots=set_slots, n_local=n_local)
     targets = base + jax.lax.broadcasted_iota(jnp.int32, (tile_n, 1), 0)
-    onehot = idx == targets                  # (TN, TI)
-    ident = _identity(semiring, o_ref.dtype)
-    val = val_ref[...]                       # (TI, TQ)
-    if semiring == "plus_times":
-        part = jax.lax.dot_general(
-            onehot.astype(o_ref.dtype), val.astype(o_ref.dtype),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=o_ref.dtype,
-        )                                    # (TN, TQ) — MXU at full width
-    else:
-        x = jnp.where(onehot[:, :, None], val[None, :, :].astype(o_ref.dtype), ident)
-        if semiring in ("min_plus", "min_src"):
-            part = jnp.min(x, axis=1)
-        else:
-            part = jnp.max(x, axis=1)
-
-    @pl.when(t == 0)
-    def _init():
-        o_ref[...] = part
-
-    @pl.when(t != 0)
-    def _acc():
-        o_ref[...] = _combine_all(semiring, o_ref[...], part)
+    _multi_combine(semiring, idx == targets, val_ref, o_ref, t)
 
 
 def packed_scatter_combine_multi_pallas(
@@ -239,7 +272,7 @@ def packed_scatter_combine_multi_pallas(
     out_dtype=None,
     tile_n: int = 128,
     tile_t: int = 128,
-    tile_q: int = 8,
+    tile_q: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Multi-query packed-id scatter-combine: words [T*width/32], val [T, Q]
@@ -254,6 +287,7 @@ def packed_scatter_combine_multi_pallas(
     out_dtype = out_dtype or val.dtype
 
     grid = (n_out // tile_n, Q // tile_q, T // tile_t)
+    layout, val_spec = _multi_val_spec(semiring, tile_t, tile_q)
     return pl.pallas_call(
         functools.partial(
             _packed_scatter_multi_kernel, semiring=semiring, tile_n=tile_n,
@@ -261,42 +295,19 @@ def packed_scatter_combine_multi_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tile_t // k), lambda i, q, t: (0, t)),
-            pl.BlockSpec((tile_t, tile_q), lambda i, q, t: (t, q)),
+            val_spec,
         ],
         out_specs=pl.BlockSpec((tile_n, tile_q), lambda i, q, t: (i, q)),
         out_shape=jax.ShapeDtypeStruct((n_out, Q), out_dtype),
         interpret=interpret,
-    )(words[None, :], val)
+    )(words[None, :], layout(val))
 
 
 def _scatter_combine_multi_kernel(idx_ref, val_ref, o_ref, *, semiring: str, tile_n: int):
     t = pl.program_id(2)
     base = pl.program_id(0) * tile_n
-    idx = idx_ref[...]                       # (1, TI)
     targets = base + jax.lax.broadcasted_iota(jnp.int32, (tile_n, 1), 0)
-    onehot = idx == targets                  # (TN, TI)
-    ident = _identity(semiring, o_ref.dtype)
-    val = val_ref[...]                       # (TI, TQ)
-    if semiring == "plus_times":
-        part = jax.lax.dot_general(
-            onehot.astype(o_ref.dtype), val.astype(o_ref.dtype),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=o_ref.dtype,
-        )                                    # (TN, TQ) — MXU at full width
-    else:
-        x = jnp.where(onehot[:, :, None], val[None, :, :].astype(o_ref.dtype), ident)
-        if semiring in ("min_plus", "min_src"):
-            part = jnp.min(x, axis=1)
-        else:
-            part = jnp.max(x, axis=1)
-
-    @pl.when(t == 0)
-    def _init():
-        o_ref[...] = part
-
-    @pl.when(t != 0)
-    def _acc():
-        o_ref[...] = _combine_all(semiring, o_ref[...], part)
+    _multi_combine(semiring, idx_ref[...] == targets, val_ref, o_ref, t)
 
 
 def scatter_combine_multi_pallas(
@@ -308,12 +319,13 @@ def scatter_combine_multi_pallas(
     out_dtype=None,
     tile_n: int = 128,
     tile_t: int = 128,
-    tile_q: int = 8,
+    tile_q: int = 128,
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Multi-query scatter-combine: idx [T], val [T, Q] -> r [n_out, Q] (the
-    serving wire format — Q values ride each shipped index).  The (TN, TI,
-    TQ) tropical temporary bounds TQ; plus_times is a pure MXU matmul."""
+    serving wire format — Q values ride each shipped index).  plus_times is
+    a pure MXU matmul; the tropical semirings reduce one query column at a
+    time.  tile_q is the full Q or a multiple of 128 (lane blocks)."""
     assert semiring in SEMIRINGS
     T, Q = val.shape
     assert idx.shape == (T,), (idx.shape, val.shape)
@@ -322,14 +334,15 @@ def scatter_combine_multi_pallas(
     out_dtype = out_dtype or val.dtype
 
     grid = (n_out // tile_n, Q // tile_q, T // tile_t)
+    layout, val_spec = _multi_val_spec(semiring, tile_t, tile_q)
     return pl.pallas_call(
         functools.partial(_scatter_combine_multi_kernel, semiring=semiring, tile_n=tile_n),
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, tile_t), lambda i, q, t: (0, t)),
-            pl.BlockSpec((tile_t, tile_q), lambda i, q, t: (t, q)),
+            val_spec,
         ],
         out_specs=pl.BlockSpec((tile_n, tile_q), lambda i, q, t: (i, q)),
         out_shape=jax.ShapeDtypeStruct((n_out, Q), out_dtype),
         interpret=interpret,
-    )(idx[None, :], val)
+    )(idx[None, :], layout(val))

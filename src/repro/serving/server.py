@@ -38,6 +38,7 @@ import numpy as np
 from repro.core import algorithms
 from repro.core.engine import PMVEngine, StepConfig, _squeeze0, placement_call
 from repro.core.gimv import GimvSpec
+from repro.core.mesh import as_auto_mesh
 from repro.faults import FetchDeadlineError, as_injector
 from repro.obs import as_recorder, as_telemetry
 from repro.serving.batcher import (
@@ -149,7 +150,6 @@ def make_batched_step(spec: GimvSpec, cfg: StepConfig, mesh=None, axis_name: str
             return _advance(matrix, v, ctx, mask, active, None)
         return jax.jit(step, donate_argnums=(1,))
 
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def body(matrix, v, ctx, mask, active):
@@ -159,11 +159,11 @@ def make_batched_step(spec: GimvSpec, cfg: StepConfig, mesh=None, axis_name: str
         return v_new[None], deltas, stats
 
     sharded, repl = P(axis_name), P()
-    step = shard_map(
+    step = jax.shard_map(
         body, mesh=mesh,
         in_specs=(sharded, sharded, sharded, sharded, repl),
         out_specs=(sharded, repl, repl),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(step, donate_argnums=(1,))
 
@@ -285,7 +285,7 @@ class PMVServer:
         self.n = int(n)
         self.b = int(b)
         self.max_iters = int(max_iters)
-        self.mesh = mesh
+        self.mesh = as_auto_mesh(mesh)
         self.axis_name = axis_name
         # obs is shared with every family engine (and through it the disk
         # executor/store), so one recorder traces the whole serving run.
@@ -295,7 +295,7 @@ class PMVServer:
             capacity=capacity, slack=slack, payload_dtype=payload_dtype,
             backend=backend, scatter=scatter, stream=stream,
             pallas_interpret=pallas_interpret,
-            base_weights=base_weights, mesh=mesh, axis_name=axis_name,
+            base_weights=base_weights, mesh=self.mesh, axis_name=axis_name,
             # normalized ONCE so every family engine shares one injector —
             # a FaultPlan's events fire once server-wide, not once per family
             obs=self.obs, faults=as_injector(faults, self.obs),

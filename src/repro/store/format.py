@@ -177,12 +177,13 @@ def pack_worker_stripe(
 
     ``inner`` is the inner block id of each edge (destination block for
     vertical stripes, source block for horizontal), seg_local/gat_local the
-    local indices.  The stable lexsort by (inner, seg_local) is
-    build_stripes' global np.lexsort((seg_local, inner, owner)) restricted
-    to one owner, so per-bin packing reproduces the in-memory stripe
-    bitwise given the global ``e_cap``.
+    local indices.  The stable sort by (inner, seg_local) is build_stripes'
+    global stable sort by (owner, inner, seg_local) restricted to one owner,
+    so per-bin packing reproduces the in-memory stripe bitwise given the
+    global ``e_cap``.
     """
-    order = np.lexsort((seg_local, inner))
+    seg_span = int(seg_local.max(initial=0)) + 1
+    order = np.argsort(inner.astype(np.int64) * seg_span + seg_local, kind="stable")
     inner_s = inner[order]
     seg_s = seg_local[order]
     gat_s = gat_local[order]

@@ -507,20 +507,20 @@ def load_partitioned(
     part = manifest.part
     stats = manifest.graph_stats()
     out_deg = stats.out_deg
-    vertical = [load_stripe(manifest, "vertical", j, spec, out_deg)
-                for j in range(manifest.b)]
-    horizontal = [load_stripe(manifest, "horizontal", i, spec, out_deg)
-                  for i in range(manifest.b)]
+    stripes = {placement: [load_stripe(manifest, placement, k, spec, out_deg)
+                           for k in range(manifest.b)]
+               for placement in ("vertical", "horizontal")}
     partial_nnz = np.asarray(manifest.array("partial_nnz"))
     pm = PartitionedMatrix(
-        part=part, stats=stats, vertical=vertical, horizontal=horizontal,
+        part=part, stats=stats,
         block_nnz=np.asarray(manifest.array("nnz")),
         partial_nnz=partial_nnz,
         partial_cap=max(int(partial_nnz.max()), 1),
+        stripes=stripes.__getitem__,
     )
     hm = None
     if theta is not None:
-        edges = _reconstruct_edges(part, vertical)
+        edges = _reconstruct_edges(part, pm.vertical)
         w = edge_weights_for(spec, out_deg, edges[:, 0]) if spec.needs_weights else None
         hm = build_hybrid(part, stats, edges, w, theta)
     return pm, hm

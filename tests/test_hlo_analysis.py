@@ -1,4 +1,5 @@
 """Trip-count-aware collective accounting (dry-run roofline input)."""
+import os
 import subprocess
 import sys
 
@@ -57,7 +58,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.launch.hlo_analysis import collective_totals
-mesh = jax.make_mesh((4,), ("model",))
+from repro.core.mesh import worker_mesh
+mesh = worker_mesh(4, "model")
 sh = NamedSharding(mesh, P(None, "model"))
 rep = NamedSharding(mesh, P())
 def f(x, ws):
@@ -77,6 +79,7 @@ assert total >= 3 * raw, (total, raw)
 print("TRIPS-OK", total, raw)
 """
     out = subprocess.run([sys.executable, "-c", script], capture_output=True, text=True,
-                         timeout=300, env={**__import__("os").environ, "PYTHONPATH": "src"},
-                         cwd="/root/repo")
+                         timeout=300,
+                         env={**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"},
+                         cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
     assert "TRIPS-OK" in out.stdout, out.stderr[-1500:]

@@ -124,6 +124,27 @@ def test_ell_gimv_multi_matches_vmapped_ref(semiring, shape):
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("semiring", ["plus_times", "min_plus"])
+@pytest.mark.parametrize("q", [None, 3])
+def test_ell_gimv_batched_tables_match_ref(semiring, q):
+    """Leading axes batch tables of one shape into one launch ([*L, D, R]
+    -> [*L, R(, Q)]); rows past one lane tile exercise the row tiling."""
+    rng = np.random.default_rng(hash(("ellbatch", semiring, q)) % 2**31)
+    L0, L1, R, N, E = 2, 3, 300, 70, 900
+    tables = [ell_from_edges(rng.integers(0, R, E), rng.integers(0, N, E),
+                             rng.random(E).astype(np.float32), R, d_cap=40)
+              for _ in range(L0 * L1)]
+    cols = np.stack([c for c, _ in tables]).reshape(L0, L1, 40, R)
+    ww = np.stack([w_ for _, w_ in tables]).reshape(cols.shape)
+    v = rng.random((N,) if q is None else (N, q)).astype(np.float32)
+    fn, ref = (ell_gimv, ell_gimv_ref) if q is None else (ell_gimv_multi, ell_gimv_multi_ref)
+    got = fn(jnp.asarray(cols), jnp.asarray(ww), jnp.asarray(v),
+             semiring=semiring, interpret=True)
+    want = ref(jnp.asarray(cols), jnp.asarray(ww), jnp.asarray(v), semiring=semiring)
+    assert got.shape == (L0, L1, R) + (() if q is None else (q,))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-5, atol=1e-5)
+
+
 @pytest.mark.parametrize("semiring", SEMIRINGS)
 def test_ell_gimv_multi_q1_equals_single(semiring):
     """Q=1 must reduce to the single-vector ELL kernel exactly."""
@@ -163,11 +184,11 @@ def test_ell_from_edges_packs_all_edges():
     src = np.array([10, 11, 12, 13, 14])
     w = np.arange(5, dtype=np.float32)
     cols, ww = ell_from_edges(dst, src, w, 4)
-    assert cols.shape == (4, 3)
-    np.testing.assert_array_equal(cols[2, :3], [10, 12, 13])
-    np.testing.assert_array_equal(ww[2, :3], [0.0, 2.0, 3.0])
-    np.testing.assert_array_equal(cols[0, :1], [11])
-    np.testing.assert_array_equal(cols[3], [-1, -1, -1])
+    assert cols.shape == (3, 4)                  # slot-major [D, rows]
+    np.testing.assert_array_equal(cols[:3, 2], [10, 12, 13])
+    np.testing.assert_array_equal(ww[:3, 2], [0.0, 2.0, 3.0])
+    np.testing.assert_array_equal(cols[:1, 0], [11])
+    np.testing.assert_array_equal(cols[:, 3], [-1, -1, -1])
 
 
 def test_ell_gimv_no_weights():

@@ -215,6 +215,24 @@ def test_engine_run_parity_planned(strategy):
     np.testing.assert_allclose(rx.v, rp.v, rtol=1e-5, atol=1e-7)
 
 
+@pytest.mark.parametrize("theta,dense", [(4.0, True), (np.inf, False)])
+def test_planned_hybrid_runs_dense_region_kernel_unless_empty(theta, dense):
+    """The planned hybrid materializes a non-empty θ-split dense region for
+    the dense kernel and skips an empty one; both match the xla solve."""
+    n = 96
+    edges = erdos_renyi(n, 420, seed=3)
+    kw = dict(b=4, strategy="hybrid", theta=theta)
+    eng = PMVEngine(edges, n, backend="auto", **kw)
+    spec = pagerank(n)
+    _, matrix, _v0, _ctx, _mask, meta = eng.prepare(spec)
+    assert meta["backend"] == "planned"
+    assert (meta["n_dense"] > 0) is dense
+    assert ("dense_matrix" in matrix) is dense
+    rp = eng.run(spec, max_iters=10, tol=0.0)
+    rx = PMVEngine(edges, n, **kw).run(pagerank(n), max_iters=10, tol=0.0)
+    np.testing.assert_allclose(rp.v, rx.v, rtol=1e-5, atol=1e-7)
+
+
 def test_serving_planned_matches_xla():
     from repro.serving import PMVServer, Query
 
@@ -455,8 +473,8 @@ def test_bucketed_ell_rows_unique_and_width_bounded(data):
     buckets = blocks_lib.pack_bucketed_ell(dst, src, None, boundaries)
     seen = []
     for k, bkt in enumerate(buckets):
-        assert bkt.cols.shape[-1] == boundaries[k]
-        for r, row in zip(np.asarray(bkt.rows), np.asarray(bkt.cols)):
+        assert bkt.cols.shape[-2] == boundaries[k]
+        for r, row in zip(np.asarray(bkt.rows), np.asarray(bkt.cols).T):
             assert deg[r] <= boundaries[k]
             assert int((row >= 0).sum()) == deg[r]
             seen.append(int(r))

@@ -35,10 +35,14 @@ from test_fuzz_parity import TOPOLOGIES, _fuzz_edges
 pytestmark = pytest.mark.filterwarnings("error")
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-ENV = {**os.environ, "PYTHONPATH": "src"}
+# The children emulate W hosts on forced CPU devices; JAX_PLATFORMS=cpu keeps
+# them off any accelerator this process may hold.
+ENV = {**os.environ, "PYTHONPATH": "src", "JAX_PLATFORMS": "cpu"}
 
 
 def _run(script: str, timeout: int = 900) -> str:
+    """Run ``script`` in a child interpreter on emulated CPU devices
+    (JAX_PLATFORMS=cpu, see ENV) and return its stdout."""
     proc = subprocess.run([sys.executable, "-c", script], capture_output=True,
                           text=True, env=ENV, cwd=REPO, timeout=timeout)
     assert proc.returncode == 0, (
@@ -60,6 +64,7 @@ import tempfile
 import numpy as np
 import jax
 from repro.core import PMVEngine, connected_components, cost_model, pagerank, sssp
+from repro.core.mesh import worker_mesh
 from repro.store import ingest_edges
 from test_fuzz_parity import _fuzz_edges
 
@@ -98,7 +103,7 @@ with tempfile.TemporaryDirectory() as d:
         r_single = single.run(spec, max_iters=4, tol=0.0)
         assert np.array_equal(ref.v, r_single.v), ("single", PSI, name)
         for W in (1, 2, 4, 8):
-            mesh = jax.make_mesh((W,), ("workers",))
+            mesh = worker_mesh(W)
             eng = PMVEngine.from_store(man, residency="disk", psi=PSI,
                                        strategy=strategy, mesh=mesh,
                                        store_budget_bytes=budget, **skw)
@@ -131,6 +136,7 @@ import tempfile
 import numpy as np
 import jax
 from repro.core import PMVEngine, pagerank
+from repro.core.mesh import worker_mesh
 from repro.store import ingest_edges
 
 n, b = 60, 6
@@ -138,7 +144,7 @@ rng = np.random.default_rng(0)
 edges = rng.integers(0, n, size=(300, 2)).astype(np.int64)
 with tempfile.TemporaryDirectory() as d:
     man = ingest_edges(edges, n, b, d + "/s")
-    mesh = jax.make_mesh((4,), ("workers",))   # 4 does not divide b=6
+    mesh = worker_mesh(4)   # 4 does not divide b=6
     try:
         PMVEngine.from_store(man, residency="disk", strategy="vertical",
                              mesh=mesh).prepare(pagerank(n))
@@ -160,6 +166,7 @@ import tempfile
 import numpy as np
 import jax
 from repro.core import PMVEngine, pagerank
+from repro.core.mesh import worker_mesh
 from repro.faults import BreakPrefetch, FaultPlan
 from repro.store import ingest_edges
 
@@ -169,7 +176,7 @@ edges = rng.integers(0, n, size=(3000, 2)).astype(np.int64)
 with tempfile.TemporaryDirectory() as d:
     man = ingest_edges(edges, n, b, d + "/s")
     spec = pagerank(n)
-    mesh = jax.make_mesh((4,), ("workers",))
+    mesh = worker_mesh(4)
     clean = PMVEngine.from_store(man, residency="disk", strategy="vertical",
                                  mesh=mesh).run(spec, max_iters=4, tol=0.0)
     plan = FaultPlan(events=(BreakPrefetch(worker=1),), seed=0)
@@ -197,6 +204,7 @@ import tempfile
 import numpy as np
 import jax
 from repro.core import PMVEngine, pagerank
+from repro.core.mesh import worker_mesh
 from repro.obs import (check_span_nesting, fleet_report, merge_traces,
                        validate_chrome_trace)
 from repro.store import ingest_edges
@@ -207,7 +215,7 @@ edges = rng.integers(0, n, size=(3000, 2)).astype(np.int64)
 with tempfile.TemporaryDirectory() as d:
     man = ingest_edges(edges, n, b, d + "/s")
     spec = pagerank(n)
-    mesh = jax.make_mesh((W,), ("workers",))
+    mesh = worker_mesh(W)
     off = PMVEngine.from_store(man, residency="disk", strategy="vertical",
                                mesh=mesh).run(spec, max_iters=4, tol=0.0)
     eng = PMVEngine.from_store(man, residency="disk", strategy="vertical",
@@ -246,6 +254,7 @@ import tempfile
 import numpy as np
 import jax
 from repro.core import PMVEngine, pagerank
+from repro.core.mesh import worker_mesh
 from repro.faults import FaultPlan, SlowFetch
 from repro.obs import fleet_report
 from repro.store import ingest_edges
@@ -256,7 +265,7 @@ edges = rng.integers(0, n, size=(3000, 2)).astype(np.int64)
 with tempfile.TemporaryDirectory() as d:
     man = ingest_edges(edges, n, b, d + "/s")
     spec = pagerank(n)
-    mesh = jax.make_mesh((W,), ("workers",))
+    mesh = worker_mesh(W)
     clean = PMVEngine.from_store(man, residency="disk", strategy="vertical",
                                  mesh=mesh).run(spec, max_iters=4, tol=0.0)
     plan = FaultPlan(events=(SlowFetch(block=1, delay_s=0.3, worker=2),),

@@ -105,17 +105,16 @@ def test_quantize_psum_error_feedback_bounds():
 
     # single-"pod" axis via a size-1 vmap-free trick: use jax.make_mesh? On a
     # 1-device CPU, shard_map with axis size 1 works.
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
+    from jax.sharding import AxisType, PartitionSpec as P
 
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = jax.make_mesh((1,), ("pod",), axis_types=(AxisType.Auto,))
     g = jnp.linspace(-3.0, 3.0, 64)
 
     def f(g):
         return quantize_psum(g, "pod")
 
-    mean_g, resid = jax.jit(shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
-                                      check_rep=False))(g)
+    mean_g, resid = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(), out_specs=P(),
+                                          check_vma=False))(g)
     scale = 3.0 / 127.0
     assert float(jnp.max(jnp.abs(resid))) <= scale / 2 + 1e-6
     np.testing.assert_allclose(np.asarray(mean_g + resid), np.asarray(g), atol=1e-6)
