@@ -3,11 +3,10 @@
 Two measurements feed the JSON:
 
 - **overhead**: the same streamed vertical PageRank solved with obs off
-  (NULL_RECORDER) and obs on (enabled Recorder, per-iteration spans with
-  block_until_ready fences).  The disabled path must be free — its median
-  wall ratio vs a plain untraced run is the headline number; the enabled
-  ratio quantifies what a fenced trace costs (fences serialize XLA's async
-  dispatch, so >1 is expected and fine).
+  (NULL_RECORDER) and obs on (enabled Recorder, per-iteration spans and
+  series; the resident loop does not fence).  The disabled path must be
+  free — its median wall ratio vs a plain untraced run is the headline
+  number; the enabled ratio quantifies what recording costs.
 - **calibration**: per-kind predicted-vs-measured residuals joining every
   launch span's wall time against the planner's cost predictions —
   ``launch.ell`` / ``launch.dense`` from the standalone block profiler,
@@ -82,7 +81,7 @@ def main(smoke: bool = False) -> int:
     spec = pagerank(N)
     base = dict(strategy="vertical", backend="auto")
 
-    # -- overhead: off must be free, on pays only for fences ----------------
+    # -- overhead: off must be free, on pays only for recording -------------
     wall_plain = _median_wall(base, edges, N, spec, solves)
     wall_off = _median_wall({**base, "obs": None}, edges, N, spec, solves)
     wall_on = _median_wall({**base, "obs": Recorder()}, edges, N, spec, solves)

@@ -1041,41 +1041,43 @@ class PMVEngine:
         resume: bool = False,
         _allow_fallback: bool = True,
     ) -> PMVResult:
-        step, matrix, v, ctx_b, mask, meta = self.prepare(spec, ctx)
-        part: Partition = meta["part"]
-        cfg: StepConfig = meta["cfg"]
+        obs = self.obs
+        with obs.span("pmv.setup"):
+            step, matrix, v, ctx_b, mask, meta = self.prepare(spec, ctx)
+            part: Partition = meta["part"]
+            cfg: StepConfig = meta["cfg"]
 
-        # delta-iteration carried state: the previously-shipped packed
-        # payload, fresh-initialized to the combineAll identity (a suppressed
-        # row then delivers the identity — a no-op — until it first moves).
-        xstate = None
-        if cfg.delta_eps is not None:
-            wire_dt = jnp.dtype(self.payload_dtype or spec.dtype)
-            xstate = jnp.full((self.b, self.b, cfg.xplan.p_dev),
-                              jnp.asarray(spec.identity, wire_dt))
-            if self.mesh is not None:
-                xstate = jax.device_put(
-                    xstate, NamedSharding(self.mesh, P(self.axis_name)))
+            # delta-iteration carried state: the previously-shipped packed
+            # payload, fresh-initialized to the combineAll identity (a
+            # suppressed row then delivers the identity — a no-op — until it
+            # first moves).
+            xstate = None
+            if cfg.delta_eps is not None:
+                wire_dt = jnp.dtype(self.payload_dtype or spec.dtype)
+                xstate = jnp.full((self.b, self.b, cfg.xplan.p_dev),
+                                  jnp.asarray(spec.identity, wire_dt))
+                if self.mesh is not None:
+                    xstate = jax.device_put(
+                        xstate, NamedSharding(self.mesh, P(self.axis_name)))
 
-        start_iter = 0
-        if resume and checkpoint_dir and os.path.exists(_ckpt_path(checkpoint_dir)):
-            try:
-                v_np, start_iter = _ckpt_load(checkpoint_dir)
-            except CheckpointCorruptError as e:
-                # _ckpt_save commits atomically (tmp + os.replace), so a
-                # corrupt state file means external truncation/disk fault —
-                # restart from v0 rather than crash the solve.
-                warnings.warn(f"ignoring corrupt checkpoint: {e}",
-                              CheckpointCorruptWarning, stacklevel=2)
-                start_iter = 0
-            else:
-                v = jnp.asarray(v_np) if self.mesh is None else jax.device_put(
-                    jnp.asarray(v_np), NamedSharding(self.mesh, P(self.axis_name)))
+            start_iter = 0
+            if resume and checkpoint_dir and os.path.exists(_ckpt_path(checkpoint_dir)):
+                try:
+                    v_np, start_iter = _ckpt_load(checkpoint_dir)
+                except CheckpointCorruptError as e:
+                    # _ckpt_save commits atomically (tmp + os.replace), so a
+                    # corrupt state file means external truncation/disk fault
+                    # — restart from v0 rather than crash the solve.
+                    warnings.warn(f"ignoring corrupt checkpoint: {e}",
+                                  CheckpointCorruptWarning, stacklevel=2)
+                    start_iter = 0
+                else:
+                    v = jnp.asarray(v_np) if self.mesh is None else jax.device_put(
+                        jnp.asarray(v_np), NamedSharding(self.mesh, P(self.axis_name)))
 
         per_iter: list[dict] = []
         converged = False
         it = start_iter
-        obs = self.obs
         for it in range(start_iter, max_iters):
             if self._fault_injector is not None:
                 # kill events fire HERE (top of the iteration, before any
@@ -1084,59 +1086,38 @@ class PMVEngine:
                 self._fault_injector.on_iteration(it)
             t0 = time.perf_counter()
             with obs.span("pmv.iteration") as sp:
-                if xstate is not None:
-                    v_new, delta, stats, xstate = step(matrix, v, ctx_b, mask, xstate)
-                else:
-                    v_new, delta, stats = step(matrix, v, ctx_b, mask)
-                # the fence makes the span cover the device work, not just
-                # the dispatch; the null recorder's fence is identity, so the
-                # untraced path keeps XLA's async schedule untouched.
-                v_new = obs.fence(v_new)
-                delta = float(delta)
+                with obs.span("pmv.dispatch"):
+                    if xstate is not None:
+                        v_new, delta, stats, xstate = step(matrix, v, ctx_b, mask, xstate)
+                    else:
+                        v_new, delta, stats = step(matrix, v, ctx_b, mask)
+                # waits for the step's execution: the span ends when the
+                # device's result is back on the host
+                with obs.span("pmv.sync"):
+                    delta = float(delta)
                 sp.set("iteration", it)
                 sp.set("delta", delta)
             wall = time.perf_counter() - t0
-            # store_worker_* breakdowns are per-worker LISTS; everything else
-            # is a scalar.
-            rec = {k: ([float(np.asarray(e)) for e in x]
-                       if isinstance(x, list) else float(np.asarray(x)))
-                   for k, x in stats.items()}
-            rec.update(delta=delta, wall_s=wall, iteration=it)
-            rec["io_elems"] = self._paper_io(meta, rec)
-            per_iter.append(rec)
-            if obs.enabled:
-                obs.counter("pmv.iterations").add(1)
-                obs.series("pmv.delta").append(delta)
-                obs.series("pmv.iter_wall_s").append(wall)
-                obs.series("pmv.exchanged_bytes").append(rec.get("exchanged_bytes", 0.0))
-                obs.series("pmv.gathered_bytes").append(rec.get("gathered_bytes", 0.0))
-                if "exchange_payload_bytes" in rec:
-                    obs.series("pmv.exchange_payload_bytes").append(
-                        rec["exchange_payload_bytes"])
-                    # packed transport ships ids once: the amortized leg
-                    # decays 1/iters; the padded stream re-pays it whole.
-                    id_b = rec.get("exchange_id_bytes", 0.0)
-                    iters_so_far = it - start_iter + 1
-                    obs.series("pmv.exchange_id_bytes_amortized").append(
-                        id_b / iters_so_far if meta.get("exchange") == "packed"
-                        else id_b)
-                if "delta_sent_rows" in rec:
-                    obs.series("pmv.delta_sent_rows").append(rec["delta_sent_rows"])
-                    obs.series("pmv.delta_suppressed_rows").append(
-                        rec["delta_suppressed_rows"])
-                if "store_bytes_read" in rec:  # disk residency: per-iter I/O
-                    obs.series("pmv.io_bytes").append(rec["store_bytes_read"])
-                    obs.series("pmv.io_overlap").append(rec["store_overlap"])
-                    # SPMD disk: per-worker disk / prefetch-wait / overlap
-                    # series (the fleet_report straggler feed)
-                    for wk, (ws, ov) in enumerate(zip(
-                            rec.get("store_worker_wait_s", ()),
-                            rec.get("store_worker_overlap", ()))):
-                        obs.series(f"pmv.io_wait_s.w{wk}").append(ws)
-                        obs.series(f"pmv.io_overlap.w{wk}").append(ov)
-                    for wk, io_w in enumerate(
-                            rec.get("store_worker_io_s", ())):
-                        obs.series(f"pmv.io_s.w{wk}").append(io_w)
+            with obs.span("pmv.stats"):
+                # store_worker_* breakdowns are per-worker LISTS; everything
+                # else is a scalar.
+                rec = {k: ([float(np.asarray(e)) for e in x]
+                           if isinstance(x, list) else float(np.asarray(x)))
+                       for k, x in stats.items()}
+                rec.update(delta=delta, wall_s=wall, iteration=it)
+                rec["io_elems"] = self._paper_io(meta, rec)
+                per_iter.append(rec)
+                if obs.enabled:
+                    obs.counter("pmv.iterations").add(1)
+                    obs.series("pmv.delta").append(delta)
+                    obs.series("pmv.iter_wall_s").append(wall)
+                    obs.series("pmv.exchanged_bytes").append(
+                        rec.get("exchanged_bytes", 0.0))
+                    obs.series("pmv.gathered_bytes").append(
+                        rec.get("gathered_bytes", 0.0))
+                    if "store_bytes_read" in rec:  # disk residency: per-iter I/O
+                        obs.series("pmv.io_bytes").append(rec["store_bytes_read"])
+                        obs.series("pmv.io_overlap").append(rec["store_overlap"])
             v = v_new
             if rec.get("overflow", 0.0) > 0:
                 fb = self.fallback_overrides(meta["strategy"]) if _allow_fallback else None
@@ -1157,7 +1138,8 @@ class PMVEngine:
                     f"{meta['capacity']} too small — rerun with capacity='structural' "
                     "or exchange='dense'")
             if checkpoint_dir and checkpoint_every and (it + 1) % checkpoint_every == 0:
-                _ckpt_save(checkpoint_dir, np.asarray(v), it + 1)
+                with obs.span("pmv.checkpoint"):
+                    _ckpt_save(checkpoint_dir, np.asarray(v), it + 1)
             if delta < tol:
                 converged = True
                 it += 1
@@ -1165,31 +1147,32 @@ class PMVEngine:
         else:
             it = max_iters
 
-        v_np = part.from_blocked(np.asarray(v))
-        totals = {
-            "physical_elems": sum(r.get("gathered_elems", 0.0) + r.get("exchanged_elems", 0.0) for r in per_iter),
-            "logical_elems": sum(r.get("logical_elems", 0.0) for r in per_iter),
-            "wall_s": sum(r["wall_s"] for r in per_iter),
-            "exchanged_bytes": sum(r.get("exchanged_bytes", 0.0) for r in per_iter),
-            "gathered_bytes": sum(r.get("gathered_bytes", 0.0) for r in per_iter),
-        }
-        if per_iter and "exchange_id_bytes" in per_iter[0]:
-            # packed transport: ids crossed the wire ONCE (prepare-time
-            # shipment), so the total counts them once; the padded stream
-            # re-ships its int32 ids every iteration.
-            id_per_iter = per_iter[0]["exchange_id_bytes"]
-            totals["exchange_id_bytes"] = (
-                id_per_iter if meta.get("exchange") == "packed"
-                else sum(r.get("exchange_id_bytes", 0.0) for r in per_iter))
-            totals["exchange_payload_bytes"] = sum(
-                r.get("exchange_payload_bytes", 0.0) for r in per_iter)
-            totals["wire_bytes"] = (totals["exchange_id_bytes"]
-                                    + totals["exchange_payload_bytes"])
-        if per_iter and "delta_sent_rows" in per_iter[0]:
-            totals["delta_sent_rows"] = sum(r["delta_sent_rows"] for r in per_iter)
-            totals["delta_suppressed_rows"] = sum(
-                r["delta_suppressed_rows"] for r in per_iter)
-        totals.update(self._io_totals(per_iter))
+        with obs.span("pmv.result"):
+            v_np = part.from_blocked(np.asarray(v))
+            totals = {
+                "physical_elems": sum(r.get("gathered_elems", 0.0) + r.get("exchanged_elems", 0.0) for r in per_iter),
+                "logical_elems": sum(r.get("logical_elems", 0.0) for r in per_iter),
+                "wall_s": sum(r["wall_s"] for r in per_iter),
+                "exchanged_bytes": sum(r.get("exchanged_bytes", 0.0) for r in per_iter),
+                "gathered_bytes": sum(r.get("gathered_bytes", 0.0) for r in per_iter),
+            }
+            if per_iter and "exchange_id_bytes" in per_iter[0]:
+                # packed transport: ids crossed the wire ONCE (prepare-time
+                # shipment), so the total counts them once; the padded stream
+                # re-ships its int32 ids every iteration.
+                id_per_iter = per_iter[0]["exchange_id_bytes"]
+                totals["exchange_id_bytes"] = (
+                    id_per_iter if meta.get("exchange") == "packed"
+                    else sum(r.get("exchange_id_bytes", 0.0) for r in per_iter))
+                totals["exchange_payload_bytes"] = sum(
+                    r.get("exchange_payload_bytes", 0.0) for r in per_iter)
+                totals["wire_bytes"] = (totals["exchange_id_bytes"]
+                                        + totals["exchange_payload_bytes"])
+            if per_iter and "delta_sent_rows" in per_iter[0]:
+                totals["delta_sent_rows"] = sum(r["delta_sent_rows"] for r in per_iter)
+                totals["delta_suppressed_rows"] = sum(
+                    r["delta_suppressed_rows"] for r in per_iter)
+            totals.update(self._io_totals(per_iter))
         return PMVResult(
             v=v_np, iterations=it, converged=converged,
             strategy=meta["strategy"], theta=meta["theta"], capacity=meta["capacity"],

@@ -5,11 +5,12 @@ minimizes per-sub-matrix communication and I/O — so the reproduction needs to
 see its own hot path.  A :class:`Recorder` collects
 
 - **spans**: wall-clock intervals with a name and optional attributes,
-  entered via ``with rec.span("pmv.iteration"):``.  Device work launched
-  inside a jitted step is asynchronous, so span bodies that end at a jit
-  boundary call :meth:`Recorder.fence` (``jax.block_until_ready``) to
-  attribute the device time to the enclosing span instead of whichever
-  span happens to synchronize later.
+  entered via ``with rec.span("pmv.iteration"):``.  While a
+  ``jax.profiler`` session is active, every span (of a recorder or of
+  :data:`NULL_RECORDER`) also enters a ``jax.profiler.TraceAnnotation`` of
+  its name, so it lands in the profiler's trace on the device trace's
+  clock.  Device work launched inside a jitted step is asynchronous: a span
+  covers host time, and device time only where its body waits for a result.
 - **metrics**: named counters / gauges / histograms / per-iteration series
   in a :class:`MetricsRegistry` (``rec.counter("exchange.bytes").add(...)``).
 
@@ -19,13 +20,13 @@ in Perfetto / ``chrome://tracing``) and :mod:`repro.obs.report`
 
 Disabled observability must cost nothing and change nothing: the
 :data:`NULL_RECORDER` singleton answers the whole API with shared no-op
-objects — ``span()`` returns one module-level null span (no allocation per
-call: the signature takes a pre-built ``attrs`` dict or None, never
-``**kwargs``), ``fence`` returns its argument WITHOUT synchronizing, and the
-null metric instruments drop writes.  The traced path is therefore bitwise
-identical with the recorder on or off (fences only reorder host timing), and
-the disabled path allocates no per-iteration Python objects — both are
-asserted by ``tests/test_obs.py``.
+objects — outside a profiler session ``span()`` returns one module-level
+null span (no allocation per call: the signature takes a pre-built
+``attrs`` dict or None, never ``**kwargs``), ``fence`` returns its argument
+WITHOUT synchronizing, and the null metric instruments drop writes.  The
+traced path is therefore bitwise identical with the recorder on or off
+(fences only reorder host timing), and the disabled path allocates no
+per-iteration Python objects — both are asserted by ``tests/test_obs.py``.
 """
 from __future__ import annotations
 
@@ -219,16 +220,31 @@ class MetricsRegistry:
 # Spans.
 # ---------------------------------------------------------------------------
 
-class _Span:
-    """One live span; records itself into the recorder at exit."""
+_trace_annotation = None  # jax.profiler.TraceAnnotation, imported on first use
 
-    __slots__ = ("_rec", "name", "attrs", "t0")
+
+def _profiling() -> bool:
+    """Whether a ``jax.profiler`` session is recording on this host."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation.is_enabled()
+
+
+class _Span:
+    """One live span; records itself into the recorder at exit, and into
+    the profiler's trace while a session is active."""
+
+    __slots__ = ("_rec", "name", "attrs", "t0", "_ann")
 
     def __init__(self, rec: "Recorder", name: str, attrs: dict | None):
         self._rec = rec
         self.name = name
         self.attrs = attrs
         self.t0 = None
+        self._ann = None
 
     def set(self, key: str, value) -> None:
         """Attach one attribute (lazily creates the attr dict)."""
@@ -237,11 +253,37 @@ class _Span:
         self.attrs[key] = value
 
     def __enter__(self) -> "_Span":
+        if _profiling():
+            self._ann = _trace_annotation(self.name)
+            self._ann.__enter__()
         self.t0 = self._rec._clock()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self._rec._finish(self)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        return False
+
+
+class _ProfiledSpan:
+    """The disabled recorder's span while a profiler session is active: it
+    reaches the profiler's trace alone, and drops attributes."""
+
+    __slots__ = ("_ann",)
+
+    def __init__(self, name: str):
+        self._ann = _trace_annotation(name)
+
+    def set(self, key, value):
+        pass
+
+    def __enter__(self):
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -407,8 +449,9 @@ class Recorder:
 
 
 class NullRecorder:
-    """Disabled recorder: every method is a shared no-op.  ``fence`` does
-    NOT synchronize — the untraced schedule is exactly the pre-obs one."""
+    """Disabled recorder: every method is a shared no-op, but for spans
+    while a profiler session is active.  ``fence`` does NOT synchronize —
+    the untraced schedule is exactly the pre-obs one."""
 
     enabled = False
     events: list = []          # immutable-by-convention shared empty list
@@ -424,8 +467,8 @@ class NullRecorder:
     def shards(self) -> list:
         return [self]
 
-    def span(self, name: str, attrs: dict | None = None) -> _NullSpan:
-        return _NULL_SPAN
+    def span(self, name: str, attrs: dict | None = None) -> _NullSpan | _ProfiledSpan:
+        return _ProfiledSpan(name) if _profiling() else _NULL_SPAN
 
     @staticmethod
     def fence(x):
