@@ -180,7 +180,8 @@ def bench_ell_padding(scale: int, m_edges: int, b: int) -> None:
     bucketed = blocks_lib.stack_planned(
         [blocks_lib.pack_planned_stripe(
             s, ("ell",) * b, n_local, layout="vertical",
-            boundaries=plan.boundaries, semiring="plus_times")
+            boundaries=plan.boundaries, semiring="plus_times",
+            split=plan.split)
          for s in pm.vertical], "plus_times")
     flat_slots = int(np.asarray(flat.cols).size)
     bucketed_slots = sum(int(np.asarray(bk.cols).size) for bk in bucketed.buckets)

@@ -52,6 +52,7 @@ __all__ = [
     "stack_planned",
     "stack_streamed",
     "planned_to_edges",
+    "ell_pack_stats",
 ]
 
 
@@ -361,7 +362,9 @@ jax.tree_util.register_dataclass(
 #           are grouped by degree into power-of-two buckets, each bucket a
 #           slot-major [D_k, R_k] table with its own (much tighter) width, so
 #           one skewed row no longer pads every row of the stripe to d_max;
-#           R_k is padded to the kernel's lane tiling (ell_rows);
+#           R_k is padded to the kernel's lane tiling (ell_rows).  With a
+#           split plan the widths stop at K and a row of degree d > K packs
+#           as ceil(d/K) rows of the K bucket (EllBucket.split);
 #   dense — near-dense blocks materialized as [n_local, n_local] semiring
 #           matrices for the MXU kernel.
 # Rows of every table carry their *flat output index* so same-tactic blocks
@@ -379,12 +382,16 @@ class EllBucket:
       [D, R] int32 slot-major gather index into the flat source vector (-1 =
       padding slot); w: [D, R] matching weights or None.  Every destination
       row lives in exactly ONE bucket (its degree picks it), so bucket
-      results scatter with plain ``set`` — no cross-bucket combine.
+      results scatter with plain ``set`` — no cross-bucket combine.  In a
+      ``split`` bucket (the widest of a split plan) a row wider than D
+      repeats over ceil(d/D) table rows, whose results combine with
+      combineAll.
     """
 
     rows: Any        # [(b_w,) R] int32; -1 = pad
     cols: Any        # [(b_w,) D, R] int32; -1 = pad
     w: Any | None    # matching weights, or None
+    split: bool = False
 
     @property
     def d_cap(self) -> int:
@@ -392,7 +399,7 @@ class EllBucket:
 
 
 jax.tree_util.register_dataclass(
-    EllBucket, data_fields=["rows", "cols", "w"], meta_fields=[])
+    EllBucket, data_fields=["rows", "cols", "w"], meta_fields=["split"])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -441,29 +448,46 @@ jax.tree_util.register_dataclass(
 )
 
 
+def _rank_in_row(rows: np.ndarray) -> np.ndarray:
+    """Position of each edge among its row's edges, in submission order."""
+    order = np.argsort(rows, kind="stable")
+    counts = np.bincount(rows)
+    starts = np.cumsum(counts) - counts
+    rank = np.empty_like(order)
+    rank[order] = np.arange(rows.size) - starts[rows[order]]
+    return rank
+
+
 def pack_bucketed_ell(
     out_rows: np.ndarray,
     cols: np.ndarray,
     w: np.ndarray | None,
     boundaries: tuple[int, ...],
+    *,
+    split: bool = False,
 ) -> tuple:
     """Flat edge arrays -> row-bucketed ELL slices.
 
     out_rows[e] is the flat output row of edge e, cols[e] its gather index.
     Each output row with degree d goes to the first bucket whose width
-    boundary >= d; bucket k is packed as a [boundaries[k], R_k] table.  All
-    len(boundaries) buckets are emitted (possibly with R_k = 0) so the pytree
-    structure is identical across workers; stack_planned drops buckets that
-    are empty on every worker.
+    boundary >= d; bucket k is packed as a [boundaries[k], R_k] table.  With
+    ``split``, a row wider than K = boundaries[-1] packs as ceil(d/K) rows of
+    the top bucket (its first K edges, in submission order, then the next K,
+    ...) and that bucket is marked ``split``.  All len(boundaries) buckets
+    are emitted (possibly with R_k = 0) so the pytree structure is identical
+    across workers; stack_planned drops buckets that are empty on every
+    worker.
     """
     out_rows = np.asarray(out_rows, dtype=np.int64)
     cols = np.asarray(cols, dtype=np.int64)
     bounds = np.asarray(boundaries, dtype=np.int64)
+    top = int(bounds[-1])
     if out_rows.size:
         deg = np.bincount(out_rows)
         present = np.nonzero(deg)[0]
-        assert int(deg.max()) <= int(bounds[-1]), (int(deg.max()), boundaries)
-        bucket_of = np.searchsorted(bounds, deg[present], side="left")
+        assert split or int(deg.max()) <= top, (int(deg.max()), boundaries)
+        bucket_of = np.minimum(np.searchsorted(bounds, deg[present], side="left"),
+                               len(boundaries) - 1)
         remap = np.full(int(out_rows.max()) + 1, -1, dtype=np.int64)
     else:
         present = np.zeros(0, dtype=np.int64)
@@ -473,21 +497,30 @@ def pack_bucketed_ell(
     has_w = w is not None
     buckets = []
     for k, cap_k in enumerate(boundaries):
+        split_k = split and k == len(boundaries) - 1
         rows_k = present[bucket_of == k]
         if rows_k.size == 0:
             buckets.append(EllBucket(
                 rows=np.zeros((0,), np.int32),
                 cols=np.full((cap_k, 0), -1, np.int32),
-                w=np.zeros((cap_k, 0), np.float32) if has_w else None))
+                w=np.zeros((cap_k, 0), np.float32) if has_w else None,
+                split=split_k))
             continue
         remap[:] = -1
         remap[rows_k] = np.arange(rows_k.size)
         sel = remap[out_rows] >= 0
+        dst = remap[out_rows[sel]]
+        if split_k:
+            # row r's pieces are table rows first[r] .. first[r] + pieces - 1
+            pieces = -(-deg[rows_k] // top)
+            first = np.cumsum(pieces) - pieces
+            dst = first[dst] + _rank_in_row(dst) // top
+            rows_k = np.repeat(rows_k, pieces)
         cols_k, w_k = _pack_ell(
-            remap[out_rows[sel]], cols[sel],
-            np.asarray(w)[sel] if has_w else None,
+            dst, cols[sel], np.asarray(w)[sel] if has_w else None,
             rows_k.size, d_cap=cap_k)
-        buckets.append(EllBucket(rows=rows_k.astype(np.int32), cols=cols_k, w=w_k))
+        buckets.append(EllBucket(rows=rows_k.astype(np.int32), cols=cols_k,
+                                 w=w_k, split=split_k))
     return tuple(buckets)
 
 
@@ -514,9 +547,11 @@ def pack_planned_stripe(
     layout: str,
     boundaries: tuple[int, ...],
     semiring: str,
+    split: bool = False,
 ) -> PlannedStripe:
     """Pack one worker's stripe against its per-block tactics (see module
-    section above).  tactics[k] is the tactic of the k-th inner block."""
+    section above).  tactics[k] is the tactic of the k-th inner block;
+    ``split`` as in :func:`pack_bucketed_ell`."""
     assert layout in ("vertical", "merged"), layout
     b = stripe.seg_local.shape[0]
     counts = np.asarray(stripe.count)
@@ -550,7 +585,7 @@ def pack_planned_stripe(
     cat = lambda xs, dt: (np.concatenate(xs) if xs else np.zeros(0, dt))
     buckets = pack_bucketed_ell(
         cat(out_rows_l, np.int64), cat(cols_l, np.int64),
-        cat(w_l, np.float32) if has_w else None, boundaries)
+        cat(w_l, np.float32) if has_w else None, boundaries, split=split)
 
     dense = None
     if dense_mats:
@@ -587,7 +622,7 @@ def stack_planned(stripes: list[PlannedStripe], semiring: str) -> PlannedStripe:
         rows = np.stack([_pad_to(x.rows, r_max, -1) for x in bs])
         cols = np.stack([_pad_rows(x.cols, r_max, -1) for x in bs])
         w = np.stack([_pad_rows(x.w, r_max, 0) for x in bs]) if has_w else None
-        out_buckets.append(EllBucket(rows=rows, cols=cols, w=w))
+        out_buckets.append(EllBucket(rows=rows, cols=cols, w=w, split=bs[0].split))
 
     k_max = max((0 if s.dense is None else s.dense.index.shape[0]) for s in stripes)
     dense = None
@@ -622,6 +657,7 @@ def pack_streamed_stripe(
     *,
     boundaries: tuple[int, ...],
     semiring: str,
+    split: bool = False,
 ) -> PlannedStripe:
     """Bucketed-ELL slices REGROUPED PER DESTINATION BLOCK for the streamed
     executor (planner.ExecutionPlan.stream='on', the per-destination-block
@@ -638,7 +674,9 @@ def pack_streamed_stripe(
     'vertical' DenseGroup layout (matrix [k, n_local, n_local], index [k]) —
     they run as per-block MXU launches outside the scan.  rows_out stays
     b * n_local (the flat partial space both schedules feed the exchange
-    from), layout='streamed'.
+    from), layout='streamed'.  ``split`` as in :func:`pack_bucketed_ell`:
+    a row splits by its degree within its block, as the fused packer
+    splits it.
     """
     b = stripe.seg_local.shape[0]
     counts = np.asarray(stripe.count)
@@ -659,7 +697,7 @@ def pack_streamed_stripe(
             seg, gat, wk = empty, empty, (empty.astype(np.float32) if has_w else None)
         elif tactics[k] == "skip" or cnt == 0:
             seg, gat, wk = empty, empty, (empty.astype(np.float32) if has_w else None)
-        per_block.append(pack_bucketed_ell(seg, gat, wk, boundaries))
+        per_block.append(pack_bucketed_ell(seg, gat, wk, boundaries, split=split))
 
     out_buckets = []
     for kk, cap_k in enumerate(boundaries):
@@ -668,7 +706,7 @@ def pack_streamed_stripe(
         rows = np.stack([_pad_to(x.rows, r_max, -1) for x in bs])
         cols = np.stack([_pad_rows(x.cols, r_max, -1) for x in bs])
         w = np.stack([_pad_rows(x.w, r_max, 0) for x in bs]) if has_w else None
-        out_buckets.append(EllBucket(rows=rows, cols=cols, w=w))
+        out_buckets.append(EllBucket(rows=rows, cols=cols, w=w, split=bs[0].split))
 
     dense = None
     if dense_mats:
@@ -707,7 +745,7 @@ def stack_streamed(
         w = None
         if has_w:
             w = np.stack([_pad_rows(x.w, r_max, 0) for x in bs], axis=worker_axis)
-        out_buckets.append(EllBucket(rows=rows, cols=cols, w=w))
+        out_buckets.append(EllBucket(rows=rows, cols=cols, w=w, split=bs[0].split))
 
     k_max = max((0 if s.dense is None else s.dense.index.shape[0]) for s in stripes)
     dense = None
@@ -724,6 +762,23 @@ def stack_streamed(
         dense = DenseGroup(matrix=np.stack(mats), index=np.stack(idxs))
     return PlannedStripe(buckets=tuple(out_buckets), dense=dense,
                          rows_out=stripes[0].rows_out, layout="streamed")
+
+
+def ell_pack_stats(stripe: PlannedStripe) -> dict:
+    """How far a packed stripe's ELL tables fill and split: ``ell_fill``
+    (edge slots over packed slots, pads included) and ``split_rows`` (table
+    rows that repeat a destination row of their table, i.e. rows added by
+    splitting)."""
+    slots = real = split_rows = 0
+    for bk in stripe.buckets:
+        cols = np.asarray(bk.cols)
+        slots += cols.size
+        real += int(np.count_nonzero(cols >= 0))
+        if bk.split:
+            r = np.sort(np.asarray(bk.rows), axis=-1)
+            split_rows += int(np.count_nonzero((r[..., 1:] == r[..., :-1])
+                                               & (r[..., 1:] >= 0)))
+    return {"ell_fill": real / slots if slots else 1.0, "split_rows": split_rows}
 
 
 def _pad_rows(a: np.ndarray, r: int, fill) -> np.ndarray:

@@ -49,6 +49,9 @@ __all__ = [
     "predicted_overlap",
     "stripe_slice_bytes",
     "prefer_disk_residency",
+    "ELL_GATHER_SLOT_S",
+    "ELL_SCATTER_ROW_S",
+    "ell_layout_seconds",
 ]
 
 
@@ -196,6 +199,23 @@ def dense_block_cost(n_local: int, mxu_advantage: float = MXU_SLOT_ADVANTAGE) ->
     """Per-iteration compute cost of a dense-tactic block: the MXU streams
     all n_local^2 cells, each ~1/mxu_advantage of a gather slot."""
     return n_local * n_local / mxu_advantage
+
+
+# Capped ELL width (planner.ell_layout): the source-value gather ahead of
+# the ELL kernel costs the same for every packed slot, pad slots included,
+# and every packed table row costs one scatter into the partial.  Read on one
+# TPU v5e from device traces of the Graph500 scale-20 PageRank and WCC
+# solves: each 1-D gather of 65,536 to 786,432 slots out of the
+# VMEM-resident source vector took 7.13 ns per slot; the exchange's
+# scatter-add combined 4,336,640 elements in 37.8 ms, ~8.7 ns per row.
+ELL_GATHER_SLOT_S = 7.13e-9
+ELL_SCATTER_ROW_S = 8.7e-9
+
+
+def ell_layout_seconds(slots: float, rows: float) -> float:
+    """Modelled per-sweep time of a packed ELL layout of ``slots`` table
+    slots over ``rows`` table rows (pads included in both)."""
+    return slots * ELL_GATHER_SLOT_S + rows * ELL_SCATTER_ROW_S
 
 
 # ---------------------------------------------------------------------------

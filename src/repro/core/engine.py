@@ -523,6 +523,7 @@ class PMVEngine:
         self._record_plan_metrics(plan)
         pack_span = rec.span("prepare.pack")
         pack_span.__enter__()
+        ell_stats = {}
         if backend == "planned":
             semiring = semiring_of(spec.combine2, spec.combine_all)
             # emulation packs the streamed layout scan-major so the executor's
@@ -535,26 +536,33 @@ class PMVEngine:
                     return "streamed", blocks_lib.stack_streamed([
                         blocks_lib.pack_streamed_stripe(
                             s, plan.tactics_for_worker(j, "vertical"), part.n_local,
-                            boundaries=plan.boundaries, semiring=semiring)
+                            boundaries=plan.boundaries, semiring=semiring,
+                            split=plan.split)
                         for j, s in enumerate(stripes)], semiring, worker_axis=w_axis)
                 return "planned", blocks_lib.stack_planned([
                     blocks_lib.pack_planned_stripe(
                         s, plan.tactics_for_worker(j, "vertical"), part.n_local,
-                        layout="vertical", boundaries=plan.boundaries, semiring=semiring)
+                        layout="vertical", boundaries=plan.boundaries, semiring=semiring,
+                        split=plan.split)
                     for j, s in enumerate(stripes)], semiring)
 
             if strategy == "horizontal":
-                matrix["planned"] = blocks_lib.stack_planned([
+                key, packed = "planned", blocks_lib.stack_planned([
                     blocks_lib.pack_planned_stripe(
                         s, plan.tactics_for_worker(i, "merged"), part.n_local,
-                        layout="merged", boundaries=plan.boundaries, semiring=semiring)
+                        layout="merged", boundaries=plan.boundaries, semiring=semiring,
+                        split=plan.split)
                     for i, s in enumerate(pm.horizontal)], semiring)
             elif strategy == "vertical":
                 key, packed = _pack_vertical(pm.vertical)
-                matrix[key] = packed
             else:
                 key, packed = _pack_vertical(hm.sparse_vertical)
-                matrix[key + "_sparse"] = packed
+                key += "_sparse"
+            matrix[key] = packed
+            ell_stats = {"ell_cap": plan.ell_cap,
+                         **blocks_lib.ell_pack_stats(packed)}
+            for k, v in ell_stats.items():
+                pack_span.set(k, v)
 
         pack_span.__exit__(None, None, None)
         real_mask = part.global_ids_grid() < self.n
@@ -603,6 +611,7 @@ class PMVEngine:
             "part": part, "pm": pm, "hm": hm, "cfg": cfg, "backend": backend,
             "plan": plan, "residency": self.residency,
             "n_dense": int(hm.dense.d_count.sum()) if hm is not None else 0,
+            **ell_stats,
             **xmeta,
         }
         return step_jit, matrix, real_mask_dev, meta
@@ -950,6 +959,10 @@ class PMVEngine:
                  "exchange": meta.get("exchange", self.exchange)}
         if meta["hm"] is not None:
             extra["dense_region_vertices"] = meta["n_dense"]
+        for k in ("ell_cap", "ell_fill", "split_rows"):
+            if k in meta:
+                extra[k] = (round(meta[k], 4) if isinstance(meta[k], float)
+                            else meta[k])
         if meta["plan"] is None:
             # hybrid out-of-core bypasses the planner: there is nothing
             # tactic-shaped to format, but explain() still reports the shape.

@@ -55,7 +55,7 @@ from jax import lax
 from repro.core import sparse_exchange
 from repro.core.blocks import BlockEdges, DenseRegion, EllStripe, PlannedStripe
 from repro.core.gimv import (GimvSpec, combine2, combine_elementwise,
-                             segment_combine, tree_combine)
+                             scatter_combine, segment_combine, tree_combine)
 from repro.exchange import runtime as packed_rt
 from repro.kernels.block_gimv import dense_gimv, dense_gimv_multi, semiring_of
 from repro.kernels.ell_spmv import ell_gimv, ell_gimv_multi
@@ -430,6 +430,16 @@ def _scatter_set(out, rows, vals, drop):
     return out.at[safe].set(vals, mode="drop")
 
 
+def _scatter_bucket(spec: GimvSpec, split: bool, out, rows, vals, drop):
+    """One ELL bucket's results into the identity-filled ``out``: a plain
+    ``set``, or, in a split bucket (a row repeats once per K-wide piece),
+    combineAll of the pieces — still exact for its unsplit rows, since the
+    identity is combineAll's neutral element."""
+    if not split:
+        return _scatter_set(out, rows, vals, drop)
+    return scatter_combine(spec, out, jnp.where(rows >= 0, rows, drop), vals)
+
+
 def _planned_dense_call(spec: GimvSpec, matrix2d, operand, interpret: bool):
     """One fused MXU/VPU launch over a dense group's materialized matrix."""
     semiring = semiring_of(spec.combine2, spec.combine_all)
@@ -460,7 +470,8 @@ def _planned_merged_gimv(spec: GimvSpec, planned: PlannedStripe, v_local,
         for bucket in planned.buckets:
             rows = jnp.where(bucket.rows >= 0, bucket.rows + woff, -1).reshape(-1)
             r = ell_gimv_call(spec, bucket.cols, bucket.w, v_flat, interpret)
-            out = _scatter_set(out, rows, r.reshape((-1,) + tail), drop)
+            out = _scatter_bucket(spec, bucket.split, out, rows,
+                                  r.reshape((-1,) + tail), drop)
         r_all = out[:drop].reshape((b_w, n_local) + tail)
         if planned.dense is not None:
             k = planned.dense.index.shape[-1]
@@ -477,7 +488,7 @@ def _planned_merged_gimv(spec: GimvSpec, planned: PlannedStripe, v_local,
     out = jnp.full((n_local + 1,) + tail, ident, spec.dtype)
     for bucket in planned.buckets:
         r = ell_gimv_call(spec, bucket.cols, bucket.w, v_flat, interpret)
-        out = _scatter_set(out, bucket.rows, r, n_local)
+        out = _scatter_bucket(spec, bucket.split, out, bucket.rows, r, n_local)
     r_all = out[:n_local]
     if planned.dense is not None:
         k = planned.dense.index.shape[-1]
@@ -507,7 +518,8 @@ def _planned_vertical_partials(spec: GimvSpec, planned: PlannedStripe, v_local,
             cols = jnp.where(bucket.cols >= 0, bucket.cols + coff, -1)
             rows = jnp.where(bucket.rows >= 0, bucket.rows + roff, -1).reshape(-1)
             r = ell_gimv_call(spec, cols, bucket.w, v_flat, interpret)
-            out = _scatter_set(out, rows, r.reshape((-1,) + tail), drop)
+            out = _scatter_bucket(spec, bucket.split, out, rows,
+                                  r.reshape((-1,) + tail), drop)
         if planned.dense is not None:
             k = planned.dense.index.shape[-1]
             ar = jnp.arange(n_local, dtype=jnp.int32)[None, :]
@@ -525,7 +537,7 @@ def _planned_vertical_partials(spec: GimvSpec, planned: PlannedStripe, v_local,
     out = jnp.full((drop + 1,) + tail, ident, spec.dtype)
     for bucket in planned.buckets:
         r = ell_gimv_call(spec, bucket.cols, bucket.w, v_local, interpret)
-        out = _scatter_set(out, bucket.rows, r, drop)
+        out = _scatter_bucket(spec, bucket.split, out, bucket.rows, r, drop)
     if planned.dense is not None:
         k = planned.dense.index.shape[-1]
         m2 = planned.dense.matrix.reshape(k * n_local, n_local)
@@ -566,6 +578,8 @@ def _streamed_planned_compact(spec: GimvSpec, streamed: PlannedStripe, v_local,
         # pytree of per-bucket arrays; scan slices the leading (block) axis.
         return tuple((bk.rows, bk.cols, bk.w) for bk in streamed.buckets)
 
+    splits = tuple(bk.split for bk in streamed.buckets)
+
     if emulation:
         b_w = v_local.shape[0]
         tail = v_local.shape[2:]
@@ -576,11 +590,12 @@ def _streamed_planned_compact(spec: GimvSpec, streamed: PlannedStripe, v_local,
 
         def body(_, bks):
             out = jnp.full((drop + 1,) + tail, ident, spec.dtype)
-            for rows, cols, w in bks:            # [b_w(, D), R] per bucket
+            for (rows, cols, w), split in zip(bks, splits):  # [b_w(, D), R]
                 cols2 = jnp.where(cols >= 0, cols + coff, -1)
                 rows2 = jnp.where(rows >= 0, rows + roff, -1).reshape(-1)
                 r = ell_gimv_call(spec, cols2, w, v_flat, interpret)
-                out = _scatter_set(out, rows2, r.reshape((-1,) + tail), drop)
+                out = _scatter_bucket(spec, split, out, rows2,
+                                      r.reshape((-1,) + tail), drop)
             partial_ = out[:drop].reshape((b_w, n_local) + tail)
             return None, sparse_exchange.compact_chunk(
                 spec, partial_, capacity, batched=batched)
@@ -605,9 +620,9 @@ def _streamed_planned_compact(spec: GimvSpec, streamed: PlannedStripe, v_local,
 
     def body(_, bks):
         out = jnp.full((n_local + 1,) + v_local.shape[1:], ident, spec.dtype)
-        for rows, cols, w in bks:                # [(D,) R] per bucket
+        for (rows, cols, w), split in zip(bks, splits):  # [(D,) R]
             r = ell_gimv_call(spec, cols, w, v_local, interpret)
-            out = _scatter_set(out, rows, r, n_local)
+            out = _scatter_bucket(spec, split, out, rows, r, n_local)
         return None, sparse_exchange.compact_chunk(
             spec, out[:n_local], capacity, batched=batched)
 
@@ -643,6 +658,8 @@ def _streamed_planned_payload(spec: GimvSpec, streamed: PlannedStripe, v_local,
     def bucket_xs():
         return tuple((bk.rows, bk.cols, bk.w) for bk in streamed.buckets)
 
+    splits = tuple(bk.split for bk in streamed.buckets)
+
     if emulation:
         b_w = v_local.shape[0]
         tail = v_local.shape[2:]
@@ -655,11 +672,12 @@ def _streamed_planned_payload(spec: GimvSpec, streamed: PlannedStripe, v_local,
         def body(_, xs_):
             bks, srows = xs_
             out = jnp.full((drop + 1,) + tail, ident, spec.dtype)
-            for rows, cols, w in bks:            # [b_w(, D), R] per bucket
+            for (rows, cols, w), split in zip(bks, splits):  # [b_w(, D), R]
                 cols2 = jnp.where(cols >= 0, cols + coff, -1)
                 rows2 = jnp.where(rows >= 0, rows + roff, -1).reshape(-1)
                 r = ell_gimv_call(spec, cols2, w, v_flat, interpret)
-                out = _scatter_set(out, rows2, r.reshape((-1,) + tail), drop)
+                out = _scatter_bucket(spec, split, out, rows2,
+                                      r.reshape((-1,) + tail), drop)
             partial_ = out[:drop].reshape((b_w, n_local) + tail)
             pay = packed_rt.gather_payload(spec, partial_, srows)
             return None, (pay, sparse_exchange.count_non_identity(spec, pay))
@@ -686,9 +704,9 @@ def _streamed_planned_payload(spec: GimvSpec, streamed: PlannedStripe, v_local,
     def body(_, xs_):
         bks, srows = xs_
         out = jnp.full((n_local + 1,) + v_local.shape[1:], ident, spec.dtype)
-        for rows, cols, w in bks:                # [(D,) R] per bucket
+        for (rows, cols, w), split in zip(bks, splits):  # [(D,) R]
             r = ell_gimv_call(spec, cols, w, v_local, interpret)
-            out = _scatter_set(out, rows, r, n_local)
+            out = _scatter_bucket(spec, split, out, rows, r, n_local)
         pay = packed_rt.gather_payload(spec, out[:n_local], srows)
         return None, (pay, sparse_exchange.count_non_identity(spec, pay))
 

@@ -14,7 +14,12 @@ cost model (cost_model.ell_block_cost / dense_block_cost) into a tactic:
     skip  — structurally empty: dropped at pack time, zero per-iteration cost;
     ell   — sparse kernel over ROW-BUCKETED ELL slices (degree buckets with
             power-of-two widths cut the padding a skewed block pays under one
-            global d_cap);
+            global d_cap).  The widths stop at a cap K that ``ell_layout``
+            picks from the degree histograms: a row wider than K splits into
+            ceil(d/K) rows of the K bucket, whose results combine with
+            combineAll, so one hub row no longer pads every stacked table of
+            a wide bucket.  Where the model prefers no cap, the widths run
+            up to d_max and nothing splits;
     dense — near-dense block materialized as a [n_local, n_local] semiring
             matrix for the MXU kernel.
 
@@ -47,12 +52,15 @@ import numpy as np
 from repro.core import cost_model
 from repro.core.blocks import BlockEdges
 from repro.core.sparse_exchange import SCATTER_METHODS
-from repro.kernels.ell_spmv import ell_width
+from repro.kernels.ell_spmv import ell_rows, ell_width
 
 __all__ = [
     "BlockPlan",
     "ExecutionPlan",
     "bucket_boundaries",
+    "ell_layout",
+    "ell_layout_seconds",
+    "ell_tables",
     "measure_blocks",
     "plan_execution",
     "plan_from_stats",
@@ -110,6 +118,7 @@ class ExecutionPlan:
     stream: str = "off"             # partial schedule: 'on' (bucket-streamed) | 'off'
     residency: str = "device"       # matrix home: 'device' | 'host' | 'disk'
     e_cap: int | None = None        # padded edge capacity of the shard slices
+    split: bool = False             # rows wider than boundaries[-1] split
 
     def __post_init__(self):
         assert self.mode in MODES, self.mode
@@ -120,6 +129,12 @@ class ExecutionPlan:
 
     def block(self, i: int, j: int) -> BlockPlan:
         return self.blocks[i * self.b + j]
+
+    @property
+    def ell_cap(self) -> int:
+        """K: the widest ELL bucket.  With ``split`` a row of in-degree
+        d > K packs as ceil(d/K) rows of that bucket."""
+        return self.boundaries[-1]
 
     def tactics_for_worker(self, worker: int, layout: str) -> tuple[str, ...]:
         """Per-inner-block tactics of one worker's stripe.
@@ -145,7 +160,9 @@ class ExecutionPlan:
         Entry i describes destination block M^(i, worker):
         ('skip',) | ('dense', n_local) | ('ell', rows_per_bucket) where
         rows_per_bucket[k] is the number of destination rows bucket k's
-        [R_k, boundaries[k]] table holds for this block.
+        [R_k, boundaries[k]] table holds for this block (with ``split``,
+        the top bucket's count includes split rows as the degree histogram
+        estimates them).
         """
         sched = []
         for i in range(self.b):
@@ -232,11 +249,18 @@ class ExecutionPlan:
         return sum(bp.cost for bp in self.blocks)
 
 
-def bucket_boundaries(d_max: int, *, max_buckets: int = MAX_BUCKETS) -> tuple[int, ...]:
+def bucket_boundaries(d_max: int, *, max_buckets: int = MAX_BUCKETS,
+                      cap: int | None = None) -> tuple[int, ...]:
     """Power-of-two ELL bucket widths up to d_max, capped at max_buckets
     (dropping from the narrow end: low-degree rows then land in the smallest
     remaining boundary, still correct, just slightly more padded).  The
-    widest is d_max at the kernel's slot alignment (``ell_width``)."""
+    widest is d_max at the kernel's slot alignment (``ell_width``).
+
+    ``cap=K`` (a power of two below d_max): the widths stop at K, and rows
+    wider than K split into K-wide rows of the top bucket."""
+    if cap is not None:
+        assert cap & (cap - 1) == 0 and cap < d_max, (cap, d_max)
+        return tuple(1 << k for k in range(cap.bit_length()))[-max_buckets:]
     bounds = []
     d = 1
     while d < max(d_max, 1):
@@ -277,16 +301,16 @@ def measure_blocks(
     return out
 
 
-def _merged_d_max(stripe: BlockEdges) -> int:
-    """Max per-row in-degree of a horizontal stripe with all inner (source)
-    blocks merged — what the merged ELL layout buckets by."""
+def _merged_deg(stripe: BlockEdges) -> np.ndarray:
+    """Per-row in-degrees (non-zero) of a horizontal stripe with all inner
+    (source) blocks merged — what the merged ELL layout buckets by."""
     counts = np.asarray(stripe.count)
     segs = [np.asarray(stripe.seg_local[k, : int(counts[k])])
             for k in range(stripe.seg_local.shape[0]) if int(counts[k])]
     if not segs:
-        return 1
+        return np.zeros(0, np.int64)
     deg = np.bincount(np.concatenate(segs))
-    return max(int(deg.max()), 1)
+    return deg[deg > 0]
 
 
 DEG_HIST_BINS = 64  # power-of-two degree histogram width (degrees < 2^63)
@@ -304,35 +328,107 @@ def deg_hist_of(deg: np.ndarray) -> np.ndarray:
     return np.bincount(bins, minlength=DEG_HIST_BINS)
 
 
-def _bucket_rows_of(rec: dict, boundaries: tuple[int, ...]) -> np.ndarray:
-    """Rows per ELL degree bucket, from either the measured per-row degrees
-    ('deg') or the manifest's power-of-two histogram ('deg_hist').
+def _hist_of(rec: dict) -> np.ndarray:
+    """A measurement record's power-of-two degree histogram: the manifest's
+    'deg_hist', or the histogram of the measured per-row degrees ('deg').
+    Plans read degrees only through it, so both classify identically."""
+    if "deg_hist" in rec:
+        return np.asarray(rec["deg_hist"], dtype=np.int64)
+    return deg_hist_of(rec["deg"])
 
-    The two agree exactly: every degree inside one histogram bin maps to the
-    same bucket because the boundary list contains only powers of two plus
-    the final d_max, so no boundary falls strictly inside a bin below d_max.
+
+def _hist_bucket_rows(hist: np.ndarray, boundaries: tuple[int, ...],
+                      split: bool = False) -> np.ndarray:
+    """Table rows per ELL bucket of degree histograms [..., H].
+
+    Unsplit, this is exact: every degree inside one histogram bin maps to
+    the same bucket because the boundary list contains only powers of two
+    plus the final d_max, so no boundary falls strictly inside a bin below
+    d_max.  With ``split``, a row of degree d > K = boundaries[-1] packs as
+    ceil(d/K) rows of the top bucket; a bin (2^(k-1), 2^k] above K holds
+    rows of 2^(k-1)/K + 1 to 2^k/K pieces each, counted at the midpoint.
     """
+    hist = np.asarray(hist, dtype=np.int64)            # [..., H]
     bounds = np.asarray(boundaries, dtype=np.int64)
-    if "deg" in rec:
-        bucket_of = np.searchsorted(bounds, rec["deg"], side="left")
-        return np.bincount(bucket_of, minlength=len(boundaries))
-    hist = np.asarray(rec["deg_hist"], dtype=np.int64)
-    out = np.zeros(len(boundaries), dtype=np.int64)
-    for k in np.nonzero(hist)[0]:
-        rep = min(int(1) << int(k), int(bounds[-1]))  # bin's top degree, capped
-        out[int(np.searchsorted(bounds, rep, side="left"))] += int(hist[k])
+    top = int(bounds[-1])
+    out = np.zeros(hist.shape[:-1] + (len(boundaries),), dtype=np.int64)
+    for k in np.nonzero(hist.reshape(-1, hist.shape[-1]).any(axis=0))[0]:
+        rep = int(1) << int(k)                         # the bin's top degree
+        if split and rep > top:
+            lo, hi = rep // (2 * top) + 1, rep // top
+            out[..., -1] += -(-hist[..., k] * (lo + hi) // 2)
+        else:
+            out[..., int(np.searchsorted(bounds, min(rep, top)))] += hist[..., k]
     return out
+
+
+def _bucket_rows_of(rec: dict, boundaries: tuple[int, ...],
+                    split: bool = False) -> np.ndarray:
+    """Rows per ELL degree bucket of one measurement record."""
+    return _hist_bucket_rows(_hist_of(rec), boundaries, split)
+
+
+def ell_layout_seconds(hists: np.ndarray, boundaries: tuple[int, ...],
+                       split: bool) -> float:
+    """Modelled per-sweep time (cost_model.ell_layout_seconds) of packing
+    the T tables whose degree histograms are ``hists`` [T, H] at these
+    widths, stacked as the packers stack them: bucket k's row count is the
+    largest over the tables at ``ell_rows`` alignment, so every table pays
+    for the fullest one."""
+    per = _hist_bucket_rows(hists, boundaries, split)  # [T, n_buckets]
+    r = np.asarray([ell_rows(int(x)) for x in per.max(axis=0)], np.int64)
+    t = len(hists)
+    slots = t * int((r * np.asarray(boundaries, np.int64)).sum())
+    return cost_model.ell_layout_seconds(slots, t * int(r.sum()))
+
+
+def ell_tables(recs: list[dict], *, b: int, n_local: int,
+               max_buckets: int = MAX_BUCKETS,
+               mxu_advantage: float = cost_model.MXU_SLOT_ADVANTAGE):
+    """(hists [b*b, H], d_max, unsplit) of a vertical layout's b*b block
+    tables, the input of ``ell_layout``: only the blocks that pack as ELL at
+    the unsplit widths count (a dense block's rows never reach a bucket),
+    though every block's table stacks all the same.  ``unsplit`` are the
+    widths up to the d_max of every block."""
+    unsplit = bucket_boundaries(max((r["d_max"] for r in recs), default=1),
+                                max_buckets=max_buckets)
+    ell = [_classify(r, k // b, k % b, n_local, unsplit,
+                     mxu_advantage).tactic == "ell"
+           for k, r in enumerate(recs)]
+    hists = np.stack([_hist_of(r) * e for r, e in zip(recs, ell)])
+    d_max = max([1] + [r["d_max"] for r, e in zip(recs, ell) if e])
+    return hists, d_max, unsplit
+
+
+def ell_layout(hists: np.ndarray, d_max: int, *,
+               max_buckets: int = MAX_BUCKETS) -> tuple[tuple[int, ...], bool]:
+    """(boundaries, split): the ELL widths of least modelled time.
+
+    The candidates are the unsplit widths up to d_max and every power-of-two
+    cap K < d_max that fits ``max_buckets``; ``hists`` [T, H] are the degree
+    histograms of the tables that stack.  Ties keep the unsplit widths, so
+    a graph whose degrees never pay for a cap packs as it always did."""
+    best = bucket_boundaries(d_max, max_buckets=max_buckets), False
+    best_s = ell_layout_seconds(hists, *best)
+    cap = 1
+    while cap < d_max and cap.bit_length() <= max_buckets:
+        cand = bucket_boundaries(d_max, max_buckets=max_buckets, cap=cap), True
+        cand_s = ell_layout_seconds(hists, *cand)
+        if cand_s < best_s:
+            best, best_s = cand, cand_s
+        cap *= 2
+    return best
 
 
 def _classify(
     rec: dict, i: int, j: int, n_local: int, boundaries: tuple[int, ...],
-    mxu_advantage: float, io_cost: float = 0.0,
+    mxu_advantage: float, io_cost: float = 0.0, split: bool = False,
 ) -> BlockPlan:
     if rec["nnz"] == 0:
         return BlockPlan(i=i, j=j, tactic="skip", nnz=0, rows=0, d_max=0,
                          occupancy=0.0, cost=0.0)
     bounds = np.asarray(boundaries, dtype=np.int64)
-    rows_per_bucket = _bucket_rows_of(rec, boundaries)
+    rows_per_bucket = _bucket_rows_of(rec, boundaries, split)
     ell_cost = cost_model.ell_block_cost(int((rows_per_bucket * bounds).sum()))
     dense_cost = cost_model.dense_block_cost(n_local, mxu_advantage)
     tactic = "dense" if dense_cost < ell_cost else "ell"
@@ -382,14 +478,17 @@ def plan_execution(
     n_local = pm.part.n_local
 
     recs = measure_blocks(stripes, b, stripe_axis=axis)
-    merged_d_max = None
+    merged_d_max = merged_hist = None
     if strategy == "horizontal":
-        merged_d_max = max((_merged_d_max(s) for s in stripes), default=1)
+        degs = [_merged_deg(s) for s in stripes]
+        merged_d_max = max([1] + [int(d.max()) for d in degs if d.size])
+        merged_hist = np.stack([deg_hist_of(d) for d in degs])
     return plan_from_stats(
         recs, b=b, n_local=n_local, strategy=strategy, mode=mode, theta=theta,
         capacity=capacity, scatter=scatter, stream=stream,
         max_buckets=max_buckets, mxu_advantage=mxu_advantage,
-        interpret=interpret, residency=residency, merged_d_max=merged_d_max)
+        interpret=interpret, residency=residency, merged_d_max=merged_d_max,
+        merged_hist=merged_hist)
 
 
 def plan_from_stats(
@@ -408,6 +507,7 @@ def plan_from_stats(
     interpret: bool = False,
     residency: str = "device",
     merged_d_max: int | None = None,
+    merged_hist: np.ndarray | None = None,
 ) -> ExecutionPlan:
     """Build an ExecutionPlan from per-block measurement records.
 
@@ -416,8 +516,12 @@ def plan_from_stats(
     carries the power-of-two degree histogram ('deg_hist', deg_hist_of)
     instead of the raw per-row degrees; both classify identically
     (_bucket_rows_of), so a plan rebuilt from a manifest equals the plan
-    measured from the in-memory stripes.  ``merged_d_max`` overrides the
-    bucket sizing for the horizontal merged layout (full per-row in-degree).
+    measured from the in-memory stripes.  ``merged_d_max`` and
+    ``merged_hist`` ([b, H], one histogram per worker of full per-row
+    in-degrees) override the bucket sizing for the horizontal merged layout.
+    The ELL widths come from ``ell_layout`` over the tables that stack: the
+    b*b per-block histograms for the vertical layouts (streamed or not, so
+    both schedules pack the same rows), the b merged ones for horizontal.
     ``residency='disk'`` adds the shard-streaming I/O term
     (cost_model.disk_block_io_cost) to every non-skip block's cost and
     records e_cap so ``io_bytes_per_iter`` can model the per-iteration read
@@ -430,17 +534,23 @@ def plan_from_stats(
         # merged layout: a destination row's ELL slots merge ALL its source
         # blocks, so buckets size to the full per-row in-degree, not the
         # per-block maximum.
-        d_max = merged_d_max
+        assert merged_hist is not None
+        d_max, hists = merged_d_max, np.asarray(merged_hist)
+        unsplit = bucket_boundaries(d_max, max_buckets=max_buckets)
     else:
-        d_max = max((r["d_max"] for r in recs), default=1)
-    boundaries = bucket_boundaries(d_max, max_buckets=max_buckets)
+        hists, d_max, unsplit = ell_tables(
+            recs, b=b, n_local=n_local, max_buckets=max_buckets,
+            mxu_advantage=mxu_advantage)
+    boundaries, split = ell_layout(hists, d_max, max_buckets=max_buckets)
+    if not split:   # up to the d_max of every block, dense ones included
+        boundaries = unsplit
     e_cap = max((r["nnz"] for r in recs), default=1)
     e_cap = max(e_cap, 1)
     io_cost = (cost_model.disk_block_io_cost(e_cap) if residency == "disk"
                else 0.0)
     blocks = tuple(
         _classify(recs[i * b + j], i, j, n_local, boundaries, mxu_advantage,
-                  io_cost=io_cost)
+                  io_cost=io_cost, split=split)
         for i in range(b) for j in range(b))
 
     if scatter == "auto":
@@ -458,7 +568,7 @@ def plan_from_stats(
         strategy=strategy, mode=mode, b=b, n_local=n_local, theta=theta,
         capacity=capacity, boundaries=boundaries, blocks=blocks,
         scatter=scatter, stream=stream, residency=residency,
-        e_cap=e_cap)
+        e_cap=e_cap, split=split)
 
 
 def format_plan(plan: ExecutionPlan, *, extra: dict | None = None) -> str:
@@ -471,6 +581,9 @@ def format_plan(plan: ExecutionPlan, *, extra: dict | None = None) -> str:
         + (f" residency={plan.residency}" if plan.residency != "device" else ""),
         f"  b={plan.b} n_local={plan.n_local} ell_buckets={plan.boundaries}",
     ]
+    if plan.split:
+        lines.append(f"  ell_cap={plan.ell_cap}: wider rows split into"
+                     f" {plan.ell_cap}-wide rows that combine")
     if plan.residency == "disk":
         lines.append(
             f"  disk I/O: ~{plan.io_bytes_per_iter()} shard bytes/iter"

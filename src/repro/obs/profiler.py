@@ -93,7 +93,7 @@ def profile_block_launches(engine, spec, ctx: dict | None = None, *,
                     (jnp.asarray(bk.cols),
                      None if bk.w is None else jnp.asarray(bk.w))
                     for bk in blocks_lib.pack_bucketed_ell(
-                        dst, src, wij, plan.boundaries)
+                        dst, src, wij, plan.boundaries, split=plan.split)
                     if bk.rows.size]
 
                 def launch(tables=tables):

@@ -326,6 +326,16 @@ class Manifest:
         in_deg = np.asarray(self.array("in_deg"))
         return max(int(in_deg.max(initial=0)), 1)
 
+    def merged_deg_hist(self) -> np.ndarray:
+        """[b, H] power-of-two histograms of each horizontal worker's merged
+        row degrees (its block's non-zero in_deg), as planner.plan_execution
+        measures them from the loaded stripes."""
+        in_deg = np.asarray(self.array("in_deg"))
+        block = self.part.block_of(np.arange(in_deg.shape[0]))
+        return np.stack([
+            planner.deg_hist_of(in_deg[(block == i) & (in_deg > 0)])
+            for i in range(self.b)])
+
     # -- integrity -----------------------------------------------------
     @property
     def checksum_algorithm(self) -> str | None:
@@ -553,4 +563,6 @@ def plan_from_manifest(
         theta=theta, capacity=capacity, scatter=scatter, stream=stream,
         interpret=interpret, residency=residency,
         merged_d_max=(manifest.merged_d_max() if strategy == "horizontal"
-                      else None))
+                      else None),
+        merged_hist=(manifest.merged_deg_hist() if strategy == "horizontal"
+                     else None))

@@ -24,7 +24,7 @@ from repro.core.engine import placement_call
 from repro.core.partition import Partition
 from repro.exchange import codec
 from repro.exchange import plan as xplan_mod
-from repro.graph import erdos_renyi
+from repro.graph import erdos_renyi, rmat
 from test_fuzz_parity import SEMIRING_CASES, TOPOLOGIES, _fuzz_edges
 
 pytestmark = pytest.mark.filterwarnings("error")
@@ -156,18 +156,32 @@ def test_engine_packed_kernel_scatter():
                                rtol=1e-5, atol=1e-7)
 
 
+@pytest.mark.parametrize("graph,backend,stream", [
+    ("erdos_renyi", "xla", "auto"),
+    ("split_rows", "auto", "on"),
+    ("split_rows", "auto", "off"),
+])
 @pytest.mark.parametrize("strategy", ["vertical", "hybrid"])
-def test_packed_batched_matches_sparse(strategy):
+def test_packed_batched_matches_sparse(strategy, graph, backend, stream):
     """Trailing-Q batches: bitwise parity, and the packed wire model charges
-    Q values per slot with no per-iteration id leg."""
-    n, b, q = 96, 4, 5
-    edges = erdos_renyi(n, 420, seed=3)
+    Q values per slot with no per-iteration id leg.  The planned cases run a
+    plan that splits wide ELL rows (streamed payload vs streamed compact,
+    and the fused layout)."""
+    b, q = 4, 5
+    if graph == "erdos_renyi":
+        n, edges = 96, erdos_renyi(96, 420, seed=3)
+    else:
+        n, edges = 128, rmat(7, 1500, seed=11)
     spec = pagerank(n)
     outs = {}
     for xch in ("sparse", "packed"):
         eng = PMVEngine(edges, n, b=b, strategy=strategy, theta=4.0,
-                        exchange=xch, scatter="segment")
+                        exchange=xch, scatter="segment", backend=backend,
+                        stream=stream)
         _, matrix, _v0, _ctx, mask, meta = eng.prepare(spec)
+        if graph == "split_rows":
+            assert meta["plan"].split and meta["split_rows"] > 0
+            assert meta["plan"].stream == stream
         rng = np.random.default_rng(0)
         vb = jnp.asarray(
             rng.random((b, meta["part"].n_local, q)).astype(np.float32))

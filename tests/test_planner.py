@@ -22,7 +22,9 @@ from repro.core import blocks as blocks_lib
 from repro.core.engine import placement_call
 from repro.core.gimv import GimvSpec
 from repro.core.sparse_exchange import scatter_partials
-from repro.graph import erdos_renyi
+from repro.core.partition import partition_graph
+from repro.graph import erdos_renyi, rmat
+from test_fuzz_parity import TOPOLOGIES, _fuzz_edges
 
 # Planner/fuzz suites run with warnings promoted to errors (CI gate).
 pytestmark = pytest.mark.filterwarnings("error")
@@ -56,6 +58,22 @@ def _tactic_mix_edges(n: int = 64, b: int = 4) -> np.ndarray:
     clique = np.array([(s, d) for s in ids0 for d in ids0])
     ring = np.stack([np.arange(n), (np.arange(n) + 1) % n], axis=1)
     return np.concatenate([clique, ring])
+
+
+def _split_rows_edges(n: int = 128, b: int = 4) -> np.ndarray:
+    """An R-MAT graph whose skewed in-degrees make the planner cap the ELL
+    width (plan.split): a few rows per block are wider than K."""
+    return rmat(7, 1500, seed=11)
+
+
+# test graphs: (n, edge factory)
+GRAPHS = {"tactic_mix": (64, _tactic_mix_edges),
+          "split_rows": (128, _split_rows_edges)}
+
+
+def _assert_split(meta):
+    assert meta["plan"].split and meta["split_rows"] > 0
+    assert meta["ell_cap"] == meta["plan"].boundaries[-1]
 
 
 def _rand_v(spec, shape, rng, n):
@@ -127,6 +145,9 @@ def test_bucket_boundaries_power_of_two_capped():
     assert planner.bucket_boundaries(64) == (1, 2, 4, 8, 16, 32, 64)
     bs = planner.bucket_boundaries(4096, max_buckets=4)
     assert len(bs) == 4 and bs[-1] == 4096
+    # a cap K < d_max stops the widths at K (wider rows split)
+    assert planner.bucket_boundaries(4096, cap=16) == (1, 2, 4, 8, 16)
+    assert planner.bucket_boundaries(5, cap=1) == (1,)
 
 
 def test_row_bucketing_reduces_padded_slots_on_skewed_graph():
@@ -163,12 +184,16 @@ def _prep(strategy, semiring, backend, edges, n, b=4):
     return spec, matrix, mask, meta
 
 
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
 @pytest.mark.parametrize("semiring", sorted(SEMIRING_CASES))
 @pytest.mark.parametrize("strategy", STRATEGIES)
-def test_planned_step_matches_forced_backends(strategy, semiring):
-    """Single + batched steps on a graph whose plan mixes all three tactics."""
-    n, b = 64, 4
-    edges = _tactic_mix_edges(n, b)
+def test_planned_step_matches_forced_backends(strategy, semiring, graph):
+    """Single + batched steps on a graph whose plan mixes all three tactics,
+    and on one whose plan caps the ELL width and splits wider rows (fused
+    vertical, hybrid and horizontal merged layouts)."""
+    b = 4
+    n, make_edges = GRAPHS[graph]
+    edges = make_edges(n, b)
     _, _, exact = SEMIRING_CASES[semiring]
     outs = {}
     for be in ("xla", "pallas", "auto"):
@@ -176,7 +201,10 @@ def test_planned_step_matches_forced_backends(strategy, semiring):
         if be == "auto":
             assert meta["backend"] == "planned"
             counts = meta["plan"].tactic_counts()
-            assert counts["dense"] > 0 and counts["skip"] > 0
+            if graph == "tactic_mix":
+                assert counts["dense"] > 0 and counts["skip"] > 0
+            else:
+                _assert_split(meta)
         rng = np.random.default_rng(0)
         nl = meta["part"].n_local
         for q in (None, 3):
@@ -233,13 +261,21 @@ def test_planned_hybrid_runs_dense_region_kernel_unless_empty(theta, dense):
     np.testing.assert_allclose(rp.v, rx.v, rtol=1e-5, atol=1e-7)
 
 
-def test_serving_planned_matches_xla():
+@pytest.mark.parametrize("graph", ["erdos_renyi", "split_rows"])
+def test_serving_planned_matches_xla(graph):
+    """Multi-query serving (ell_gimv_multi) under the plan == the xla
+    baseline, including a plan whose wide rows split."""
     from repro.serving import PMVServer, Query
 
     n = 128
-    edges = erdos_renyi(n, 700, seed=9)
+    edges = (erdos_renyi(n, 700, seed=9) if graph == "erdos_renyi"
+             else _split_rows_edges(n))
     queries = [Query("rwr", source=s, tol=1e-7) for s in (3, 50, 101)]
     res = {}
+    if graph == "split_rows":
+        eng = PMVEngine(edges, n, b=4, strategy="hybrid", theta=8.0,
+                        backend="auto")
+        _assert_split(eng.prepare(pagerank(n))[-1])
     for be in ("xla", "auto"):
         srv = PMVServer(edges, n, b=4, strategy="hybrid", theta=8.0,
                         buckets=(4,), backend=be)
@@ -481,6 +517,102 @@ def test_bucketed_ell_rows_unique_and_width_bounded(data):
     assert sorted(seen) == sorted(np.nonzero(deg)[0].tolist())
 
 
+@given(data=st.data())
+@settings(max_examples=15, deadline=None)
+def test_bucketed_ell_split_rows_pack_whole_edges(data):
+    """A split pack (widths capped at K) puts each row of degree d > K in
+    ceil(d/K) rows of the top bucket, every piece full but the last, and
+    unpacks to exactly the edges it was given."""
+    n_local = data.draw(st.integers(2, 32))
+    e = data.draw(st.integers(1, 120))
+    cap = data.draw(st.sampled_from([1, 2, 4, 8]))
+    seed = data.draw(st.integers(0, 1000))
+    rng = np.random.default_rng(seed)
+    dst = np.where(rng.random(e) < 0.5, 0, rng.integers(0, n_local, e))
+    src = rng.integers(0, n_local, e)
+    w = rng.random(e).astype(np.float32)
+    deg = np.bincount(dst, minlength=n_local)
+    if deg.max() <= cap:   # the widest power of two below d_max
+        cap = 1 << (int(deg.max()) - 1).bit_length() - 1 if deg.max() > 1 else None
+    if cap is None:
+        return
+    boundaries = planner.bucket_boundaries(int(deg.max()), cap=cap)
+    buckets = blocks_lib.pack_bucketed_ell(dst, src, w, boundaries, split=True)
+    assert [bk.split for bk in buckets] == [False] * (len(buckets) - 1) + [True]
+    pieces = {}
+    for k, bkt in enumerate(buckets):
+        assert bkt.cols.shape[-2] == boundaries[k]
+        for r, row in zip(np.asarray(bkt.rows), np.asarray(bkt.cols).T):
+            pieces.setdefault(int(r), []).append(int((row >= 0).sum()))
+    for r, sizes in pieces.items():
+        if deg[r] > cap:
+            assert sizes == [cap] * (deg[r] // cap) + ([deg[r] % cap] if deg[r] % cap else [])
+        else:
+            assert sizes == [deg[r]]
+    planned = blocks_lib.PlannedStripe(buckets=buckets, dense=None,
+                                       rows_out=n_local, layout="vertical")
+    got_rows, got_cols, got_w = blocks_lib.planned_to_edges(planned)
+    order = np.lexsort((src, dst))
+    np.testing.assert_array_equal(got_rows, dst[order])
+    np.testing.assert_array_equal(got_cols, src[order])
+    np.testing.assert_allclose(np.sort(got_w), np.sort(w))
+    stats = blocks_lib.ell_pack_stats(planned)
+    assert stats["split_rows"] == sum(len(v) - 1 for v in pieces.values())
+
+
+@given(data=st.data())
+@settings(max_examples=20, deadline=None)
+def test_ell_layout_never_models_worse_than_unsplit(data):
+    """On the fuzz topologies and R-MAT, a plan splits rows only where its
+    modelled time undercuts the unsplit widths'; otherwise it keeps the
+    unsplit widths."""
+    topology = data.draw(st.sampled_from(TOPOLOGIES + ("rmat",)), label="topology")
+    b = data.draw(st.sampled_from([2, 4]), label="b")
+    n = b * data.draw(st.integers(3, 32), label="n_over_b")
+    seed = data.draw(st.integers(0, 10_000), label="seed")
+    rng = np.random.default_rng(seed)
+    if topology == "rmat":
+        n = 128
+        edges = rmat(7, int(rng.integers(200, 2000)), seed=seed)
+    else:
+        edges = _fuzz_edges(topology, n, b, rng)
+    pm, _ = partition_graph(edges, n, b, pagerank(n))
+    plan = planner.plan_execution(pm, None, strategy="vertical", mode="planned",
+                                  capacity=pm.partial_cap)
+    recs = planner.measure_blocks(pm.vertical, b, stripe_axis="gat")
+    hists, d_max, unsplit = planner.ell_tables(
+        recs, b=b, n_local=pm.part.n_local)
+    if plan.split:
+        assert plan.boundaries[-1] < d_max
+        assert (planner.ell_layout_seconds(hists, plan.boundaries, True)
+                < planner.ell_layout_seconds(
+                    hists, planner.bucket_boundaries(d_max), False))
+    else:
+        assert plan.boundaries == unsplit
+
+
+def test_explain_and_pack_span_report_split():
+    """ell_cap, ell_fill and split_rows reach explain(), the prepare
+    metadata and the prepare.pack span."""
+    from repro.obs import Recorder
+
+    n = 128
+    rec = Recorder()
+    eng = PMVEngine(_split_rows_edges(n), n, b=4, strategy="vertical",
+                    backend="auto", obs=rec)
+    _, matrix, _v0, _c, _mask, meta = eng.prepare(pagerank(n))
+    _assert_split(meta)
+    packed = blocks_lib.ell_pack_stats(matrix["planned"])
+    assert meta["ell_fill"] == packed["ell_fill"] and 0 < meta["ell_fill"] < 1
+    (span,) = rec.spans("prepare.pack")
+    assert {k: span["attrs"][k] for k in ("ell_cap", "ell_fill", "split_rows")} == \
+        {k: meta[k] for k in ("ell_cap", "ell_fill", "split_rows")}
+    report = eng.explain(pagerank(n))
+    assert f"ell_cap={meta['ell_cap']}:" in report
+    assert f"split_rows={meta['split_rows']}" in report
+    assert "ell_fill=" in report
+
+
 # ---------------------------------------------------------------------------
 # explain().
 # ---------------------------------------------------------------------------
@@ -537,12 +669,15 @@ def test_stream_forced_on_degrades_where_nothing_streams():
         assert "streamed" not in matrix
 
 
-def test_streamed_step_bitwise_matches_materialized():
-    """stream='on' vs 'off' on the tactic-mix graph (all three tactics):
-    bitwise-identical outputs and identical logical/overflow counters for
-    single and batched steps, vertical and hybrid."""
-    n, b = 64, 4
-    edges = _tactic_mix_edges(n, b)
+@pytest.mark.parametrize("graph", sorted(GRAPHS))
+def test_streamed_step_bitwise_matches_materialized(graph):
+    """stream='on' vs 'off' on the tactic-mix graph (all three tactics) and
+    on a graph whose rows split: bitwise-identical outputs and identical
+    logical/overflow counters for single and batched steps, vertical and
+    hybrid (split rows combine in the same order on both schedules)."""
+    b = 4
+    n, make_edges = GRAPHS[graph]
+    edges = make_edges(n, b)
     rng = np.random.default_rng(7)
     for strategy in ("vertical", "hybrid"):
         outs = {}
@@ -553,7 +688,10 @@ def test_streamed_step_bitwise_matches_materialized():
                             backend="auto", stream=stream)
             _, matrix, _v0, _c, mask, meta = eng.prepare(spec)
             assert meta["plan"].stream == stream
-            assert meta["plan"].tactic_counts()["dense"] > 0  # dense streamed too
+            if graph == "tactic_mix":
+                assert meta["plan"].tactic_counts()["dense"] > 0  # dense streamed too
+            else:
+                _assert_split(meta)
             nl = meta["part"].n_local
             for q in (None, 3):
                 shape = (b, nl) if q is None else (b, nl, q)
@@ -680,6 +818,51 @@ def test_explain_reports_memory_profile_and_stream():
     report = eng.explain(pagerank(n))
     assert "stream=on" in report
     assert "memory profile: materialized" in report
+
+
+@pytest.mark.slow
+def test_split_rows_spmd_matches_emulation():
+    """A plan that splits wide rows, under shard_map at W=4 (4 fake
+    devices) == emulation: streamed compact and streamed payload (packed
+    exchange), fused vertical, horizontal merged and hybrid; bitwise for
+    the selection semiring, allclose for plus_times."""
+    script = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np, jax
+from repro.core import PMVEngine, connected_components, pagerank
+from repro.graph import rmat
+n = 128
+edges = rmat(7, 1500, seed=11)
+mesh = jax.make_mesh((4,), ("workers",))
+for strategy, stream, exchange in (("vertical", "on", "sparse"),
+                                   ("vertical", "on", "packed"),
+                                   ("vertical", "off", "sparse"),
+                                   ("horizontal", "off", "sparse"),
+                                   ("hybrid", "on", "sparse")):
+    for spec, sym, exact in ((pagerank(n), False, False),
+                             (connected_components(), True, True)):
+        kw = dict(b=4, strategy=strategy, theta=8.0, symmetrize=sym,
+                  backend="auto", stream=stream, exchange=exchange)
+        e_emul = PMVEngine(edges, n, **kw)
+        assert e_emul.prepare(spec)[-1]["plan"].split, (strategy, stream)
+        r_emul = e_emul.run(spec, max_iters=5, tol=0.0)
+        r_spmd = PMVEngine(edges, n, mesh=mesh, **kw).run(spec, max_iters=5, tol=0.0)
+        r_xla = PMVEngine(edges, n, b=4, strategy=strategy, theta=8.0,
+                          symmetrize=sym).run(spec, max_iters=5, tol=0.0)
+        if exact:
+            np.testing.assert_array_equal(r_spmd.v, r_emul.v)
+            np.testing.assert_array_equal(r_emul.v, r_xla.v)
+        else:
+            np.testing.assert_allclose(r_spmd.v, r_emul.v, rtol=1e-6, atol=1e-9)
+            np.testing.assert_allclose(r_emul.v, r_xla.v, rtol=1e-5, atol=1e-7)
+print("SPLIT-SPMD-OK")
+"""
+    repo_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    out = subprocess.run([sys.executable, "-c", script], capture_output=True,
+                         text=True, timeout=560,
+                         env={**os.environ, "PYTHONPATH": "src"}, cwd=repo_root)
+    assert "SPLIT-SPMD-OK" in out.stdout, (out.stdout, out.stderr[-2000:])
 
 
 @pytest.mark.slow

@@ -82,10 +82,10 @@ def _assert_roundtrip(edges, n, b, psi, theta, spec, tmp, *, chunk, symmetrize=F
         tactics = plan.tactics_for_worker(j, "vertical")
         p0 = blocks_lib.pack_planned_stripe(
             s0, tactics, nl, layout="vertical", boundaries=plan.boundaries,
-            semiring=semiring)
+            semiring=semiring, split=plan.split)
         p1 = blocks_lib.pack_planned_stripe(
             s1, tactics, nl, layout="vertical", boundaries=plan.boundaries,
-            semiring=semiring)
+            semiring=semiring, split=plan.split)
         _assert_planned_equal(p0, p1)
 
     if theta is None:
@@ -143,12 +143,14 @@ def test_roundtrip_bitwise_symmetrized(data):
                           chunk=1 + int(chunk), symmetrize=True)
 
 
-def test_plan_from_manifest_matches_measured(tmp_path):
+@pytest.mark.parametrize("m_edges,split", [(700, None), (1500, True)])
+def test_plan_from_manifest_matches_measured(tmp_path, m_edges, split):
     """Plans rebuilt from the manifest's persisted measurements (pow2 degree
     histograms) equal plans measured from the in-memory stripes — tactics,
-    bucket_rows, and costs included."""
+    bucket_rows, and costs included — also where the plan caps the ELL
+    width and splits wider rows."""
     n, b = 128, 4
-    edges = rmat(7, 700, seed=11)
+    edges = rmat(7, m_edges, seed=11)
     pm, _ = partition_graph(edges, n, b, pagerank(n))
     man = ingest_edges(edges, n, b, str(tmp_path / "s"), chunk_edges=101)
     for strategy in ("vertical", "horizontal"):
@@ -161,6 +163,7 @@ def test_plan_from_manifest_matches_measured(tmp_path):
             man, strategy=strategy, mode="xla", capacity=cap,
             scatter="segment", stream=stream, interpret=True)
         assert p0 == p1
+        assert split is None or p1.split == split
         assert p1.residency == "disk" and p1.io_bytes_per_iter() > 0
 
 
