@@ -18,7 +18,11 @@ import json
 import os
 import sys
 
-sys.path.pop(0)  # bench/ itself: keep bench/trace.py off the import path
+# run as a script, bench/ is sys.path[0]: drop it so bench/trace.py never
+# shadows the standard library's ``trace`` (imported as a module, keep sys.path)
+if sys.path and os.path.abspath(sys.path[0] or ".") == os.path.dirname(
+        os.path.abspath(__file__)):
+    sys.path.pop(0)
 sys.path[:0] = [os.path.dirname(os.path.dirname(os.path.abspath(__file__)))]
 
 from bench import run  # noqa: E402

@@ -16,7 +16,8 @@ run:
 5. warms up with ``run(spec, max_iters=1)``, which compiles the step;
 6. ``--trace 0``: runs whole solves through ``PMVEngine.run`` back to back
    until ``--seconds`` have passed, finishing the solve in progress;
-   ``--trace 1``: runs one solve under the profiler instead;
+   ``--trace 1``: runs one solve under the profiler instead, and keeps the
+   compiled step's HLO text beside the profile (``STEP_HLO``);
 7. reads ``peak_bytes_in_use`` of every device of the cell, frees the engine,
    and compares every solve's vector with the plain reference (ref.py);
 8. prints the result as the last line of standard output: the cell's
@@ -62,6 +63,8 @@ from bench.reading import Reading  # noqa: E402
 
 COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 TRACE_DIR = os.path.join(ROOT, "bench_out", "trace")
+# the traced run's compiled step, beside its profile in the trace directory
+STEP_HLO = "step.hlo.txt"
 
 
 def log(*parts) -> None:
@@ -193,15 +196,14 @@ def departures(config: dict, meta: dict, matrix, chips: int,
     return out
 
 
-def step_op_kinds(eng, spec) -> tuple[dict, str | None]:
-    """``hlo.op_kinds`` and the module name of the engine's compiled step,
-    loaded again from the compile cache (the step without delta-iteration
-    state takes 4 arguments)."""
+def step_hlo(eng, spec) -> str | None:
+    """The optimized HLO text of the engine's compiled step, loaded again
+    from the compile cache; None for the step with delta-iteration state,
+    which takes 5 arguments."""
     step, matrix, v0, ctx, mask, meta = eng.prepare(spec)
     if meta["cfg"].delta_eps is not None:
-        return {}, None
-    text = step.lower(matrix, v0, ctx, mask).compile().as_text()
-    return hlo.op_kinds(text), hlo.module_name(text)
+        return None
+    return step.lower(matrix, v0, ctx, mask).compile().as_text()
 
 
 def tree_bytes(tree) -> int:
@@ -335,7 +337,12 @@ def run_cell(cell: dict, seed: int, seconds: float, trace: bool, devices: list, 
         f" gc_pause_s={sum(p for _, p in GC_PAUSES.pauses):.4f}"
         f" longest_gc={max(GC_PAUSES.pauses, key=lambda g: g[1], default=None)}")
 
-    kinds, step_module = step_op_kinds(eng, spec) if trace else ({}, None)
+    step_text = step_hlo(eng, spec) if trace else None
+    kinds, step_module = ((hlo.op_kinds(step_text), hlo.module_name(step_text))
+                          if step_text else ({}, None))
+    if step_text:
+        with open(os.path.join(trace_dir, STEP_HLO), "w") as f:
+            f.write(step_text)
     del eng, res
     gc.collect()
     jax.clear_caches()

@@ -2,7 +2,7 @@
 import numpy as np
 import pytest
 
-from _bench import CELLS, small_cell
+from _bench import CELLS, CHIPS, small_cell, with_devices
 from bench import gen
 
 ABC = (0.57, 0.19, 0.19)
@@ -85,10 +85,8 @@ def test_edge_slots_match_the_host_generator_within_one_percent():
     assert abs(len(e) / len(host) - 1) < 0.01
 
 
-@pytest.mark.parametrize("cell", CELLS)
-def test_every_seed_compiles_the_same_step(cell):
-    """Two seeds build the same step program, constants and all, so a run
-    with a new seed finds it in the compile cache."""
+def _step_texts(cell: str) -> list[str]:
+    """The step program of two seeds, lowered over the cell's own devices."""
     import re
 
     import jax
@@ -102,8 +100,19 @@ def test_every_seed_compiles_the_same_step(cell):
         edges, n = gen.graph500(conf["scale"], conf["edgefactor"], *ABC,
                                 conf["graph_seed"], seed, conf["layout"]["blocks"])
         spec = run.make_spec(c["traffic"], n)
-        eng = run.build_engine(conf, edges, n, jax.devices()[:1])
+        eng = run.build_engine(conf, edges, n, jax.devices()[:CHIPS[cell]])
         step, matrix, v0, ctx, mask, _ = eng.prepare(spec)
         text = step.lower(matrix, v0, ctx, mask).as_text()
         texts.append(re.sub(r"loc\(.*?\)", "", text))
+    return texts
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_every_seed_compiles_the_same_step(cell):
+    """Two seeds build the same step program, constants and all, so a run
+    with a new seed finds it in the compile cache.  A cell on more chips
+    than this process sees builds its mesh in a child with that many
+    devices."""
+    texts = with_devices(CHIPS[cell], _step_texts, cell)
+    assert f"mhlo.num_partitions = {CHIPS[cell]} " in texts[0]
     assert texts[0] == texts[1]

@@ -3,28 +3,40 @@ and the comparisons read what they claim."""
 import numpy as np
 import pytest
 
-from _bench import ONE_CHIP_CELLS, run, small_cell
+from _bench import CELLS, CHIPS, run, small_cell, with_devices
 from bench import gen, ref
+
+
+def _graph():
+    return gen.graph500(10, 16, 0.57, 0.19, 0.19, 20221, 2**31 + 1, 16)
 
 
 @pytest.fixture(scope="module")
 def graph():
-    return gen.graph500(10, 16, 0.57, 0.19, 0.19, 20221, 2**31 + 1, 16)
+    return _graph()
 
 
-@pytest.mark.parametrize("cell", ONE_CHIP_CELLS)
-def test_pagerank_reference_agrees_with_the_engine(graph, cell):
-    """On the layout of each one-chip configuration."""
+def _pagerank_against_reference(cell: str) -> tuple[float, float]:
+    """The engine's ranks on the cell's layout and devices against the
+    reference's: the largest relative error, and the reference's sum."""
     import jax
     from repro.core import pagerank
 
-    edges, n = graph
-    eng = run.build_engine(small_cell(cell)["config"], edges, n, jax.devices()[:1])
-
+    edges, n = _graph()
+    eng = run.build_engine(small_cell(cell)["config"], edges, n,
+                           jax.devices()[:CHIPS[cell]])
     got = eng.run(pagerank(n), max_iters=20, tol=0.0).v
     want = ref.pagerank(edges, n, 20)
-    assert ref.max_rel_err(got, want) < 1e-5
-    assert want.sum() <= 1.0  # sink rank leaks, as the engine's semantics say
+    return ref.max_rel_err(got, want), float(want.sum())
+
+
+@pytest.mark.parametrize("cell", CELLS)
+def test_pagerank_reference_agrees_with_the_engine(cell):
+    """On the layout of each configuration, a mesh over as many devices as
+    its cell asks for."""
+    err, total = with_devices(CHIPS[cell], _pagerank_against_reference, cell)
+    assert err < 1e-5
+    assert total <= 1.0  # sink rank leaks, as the engine's semantics say
 
 
 def test_wcc_reference_agrees_with_the_engine(graph):

@@ -81,7 +81,8 @@ def test_recorded_trace_is_small_and_has_device_ops(path):
 def test_op_name_patterns_match_the_recorded_trace(metric):
     """Each reader finds its ops in a trace recorded on the chip, with the
     op kinds of the same step compiled for a described v5e
-    (``<trace>.hlo_kinds.json``, what ``run.step_op_kinds`` reads on the chip)."""
+    (``<trace>.hlo_kinds.json``, what ``run.run_cell`` reads from
+    ``run.step_hlo`` on the chip)."""
     import json
 
     assert RECORDED, "no recorded trace under bench/testdata"
@@ -109,7 +110,8 @@ def test_hlo_op_kinds_of_the_compiled_step():
     edges, n = gen.graph500(10, 16, 0.57, 0.19, 0.19, 20221, 4, 4)
     spec = run.make_spec(cell["traffic"], n)
     eng = run.build_engine(cell["config"], edges, n, jax.devices()[:1])
-    kinds, module = run.step_op_kinds(eng, spec)
+    text = run.step_hlo(eng, spec)
+    kinds, module = hlo.op_kinds(text), hlo.module_name(text)
     assert module == "jit_step"
     gathers = [k for k, ops in kinds.items() if "gather" in ops]
     scatters = [k for k, ops in kinds.items() if "scatter" in ops]
